@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import subprocess
 import sys
 import time
@@ -60,24 +59,26 @@ def compile_guard() -> dict:
     """Runtime sanitizer lane (repro.analysis.sanitizer) in a fresh
     subprocess: transfer-guarded fused steps plus warm/steady compile
     counts.  A subprocess because compile counting must start from an
-    empty executable cache — the bench process has already compiled
-    dozens of step variants by the time this section runs.
+    empty executable cache.
+
+    ``main()`` runs it before this process imports JAX, and the child
+    inherits the platform choice of the environment: on a machine with
+    a chip the child holds it alone and exits before the parent starts.
+    The section records the platform the child ran on.
 
     The counts are deterministic (same engines, same shape layout every
     run), so check_bench gates them exactly: steady_new_executables
     must be 0 and warm_executables must not grow past the committed
     baseline."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "repro.analysis.sanitizer", "--json"],
-        capture_output=True, text=True, env=env)
+        capture_output=True, text=True)
     try:
         doc = json.loads(proc.stdout)
     except json.JSONDecodeError:
         return {"ok": False,
                 "error": (proc.stderr or proc.stdout)[-2000:]}
-    out = {"ok": doc["ok"]}
+    out = {"ok": doc["ok"], "platform": doc["platform"]}
     for res in doc["scenarios"]:
         out[res["scenario"]] = {
             k: res.get(k) for k in ("warm_executables",
@@ -627,7 +628,10 @@ def router_compare(cfg, params, smoke: bool = False):
 
 
 def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
-        out_path: str = "BENCH_serve.json"):
+        out_path: str = "BENCH_serve.json", guard: dict | None = None):
+    """All sections in this process. ``guard`` is the compile_guard()
+    result, which has to be taken before this process touches JAX
+    (``main()`` does); without it the section is left out."""
     import jax
 
     from repro.configs import get_config
@@ -765,9 +769,6 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
     # -- learned rank policy: trace -> offline train -> replay ----------
     learned_res = learned_policy_compare(cfg, params, smoke=smoke)
 
-    # -- runtime sanitizer: transfer guard + steady-state compile count -
-    guard_res = compile_guard()
-
     out = {
         "workload": {"n_requests": n_requests, "max_new": max_new,
                      "prompt_lens": [len(w["tokens"]) for w in workload],
@@ -782,9 +783,10 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
         "router": router_res,
         "obs": obs_res,
         "learned_policy": learned_res,
-        "compile_guard": guard_res,
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
     }
+    if guard is not None:
+        out["compile_guard"] = guard
     with open(out_path, "w") as f:
         json.dump(out, f, indent=2)
     return out
@@ -798,8 +800,13 @@ def main():
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    # runtime sanitizer: transfer guard + steady-state compile count, in
+    # a child that holds the device alone, before this process imports JAX
+    guard = compile_guard()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     res = run(quick=args.quick, smoke=args.smoke, n_slots=args.slots,
-              out_path=args.out)
+              out_path=args.out, guard=guard)
     e, s = res["engine"], res["sequential"]
     print(f"engine     : {e['tok_per_s']:8.1f} tok/s  "
           f"p50 {e['p50_ms']:.1f} ms  p95 {e['p95_ms']:.1f} ms  "

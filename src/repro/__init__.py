@@ -1,4 +1,1 @@
 """repro: paper reproduction framework (models, kernels, dist, launch)."""
-from repro.compat import ensure_jax_compat
-
-ensure_jax_compat()

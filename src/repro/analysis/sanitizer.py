@@ -237,7 +237,9 @@ def main(argv=None) -> int:
         results.append(res)
         failed = failed or not res["ok"]
 
-    out = {"ok": not failed, "scenarios": results}
+    import jax
+    out = {"ok": not failed, "platform": jax.default_backend(),
+           "scenarios": results}
     if args.json:
         print(json.dumps(out, indent=2))
     else:

@@ -13,6 +13,7 @@ import argparse
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import get_model
 from repro.serve.api import (AdaptiveServer, Engine, EngineConfig,
                              SamplingParams)
@@ -23,13 +24,14 @@ __all__ = ["AdaptiveServer", "main"]
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="drrl-paper")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=64)
     ap.add_argument("--chunk", type=int, default=16,
                     help="prefill chunk size (0 = legacy one-shot prefill)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     fns = get_model(cfg)

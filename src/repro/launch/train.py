@@ -16,6 +16,7 @@ from repro.configs import get_config
 from repro.configs.base import TrainConfig
 from repro.data.synthetic import SyntheticLM
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import get_model
 from repro.train.loop import run_training
@@ -33,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--grad-compression", default="none")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     fns = get_model(cfg)

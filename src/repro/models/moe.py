@@ -18,18 +18,6 @@ from repro import nn
 from repro.configs.base import ModelConfig
 
 
-@jax.custom_jvp
-def _grad_barrier(x):
-    # optimization_barrier has no AD rule on the pinned jax; the barrier is
-    # an identity, so its tangent passes straight through
-    return jax.lax.optimization_barrier(x)
-
-
-@_grad_barrier.defjvp
-def _grad_barrier_jvp(primals, tangents):
-    return _grad_barrier(primals[0]), tangents[0]
-
-
 def init_moe(cfg: ModelConfig, rng, dtype) -> Dict[str, jnp.ndarray]:
     assert cfg.moe is not None
     m, d = cfg.moe, cfg.d_model
@@ -71,7 +59,7 @@ def moe_ffn(cfg: ModelConfig, p: Dict[str, jnp.ndarray], x: jnp.ndarray
     logits = jnp.einsum("td,de->te", xf, p["router"].astype(x.dtype),
                         preferred_element_type=jnp.float32)
     # barrier: keep the gather source pinned to the bf16 value
-    xf = _grad_barrier(xf)
+    xf = jax.lax.optimization_barrier(xf)
     probs = jax.nn.softmax(logits, axis=-1)                       # (T, E)
     gates, idx = jax.lax.top_k(probs, K)                          # (T, K)
     gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)

@@ -30,6 +30,7 @@ for servers that multiplex many handles.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import warnings
@@ -360,30 +361,40 @@ class Engine:
     """Streaming request/response front-end over the continuous-batching
     core (:class:`repro.serve.ServeEngine`): ``submit() -> RequestHandle``,
     an explicit ``step()``/``run()`` loop, incremental token delivery and
-    per-request TTFT."""
+    per-request TTFT.
+
+    ``device`` pins the engine to one local device: its params, page
+    pools and control state live there, and its steps run there (the
+    :class:`repro.serve.frontend.Router` gives each replica its own).
+    ``None`` leaves placement to JAX's default device."""
 
     def __init__(self, cfg: ModelConfig, params, policy_params=None, *,
-                 config: Optional[EngineConfig] = None):
+                 config: Optional[EngineConfig] = None, device=None):
         self.config = config or EngineConfig()
         c = self.config
-        self.core = ServeEngine(
-            cfg, params, policy_params,
-            n_slots=c.n_slots, max_len=c.max_len, page_size=c.page_size,
-            segment_len=c.segment_len, buckets=c.buckets,
-            max_new_cap=c.max_new_cap, use_kernel=c.use_kernel,
-            drift_threshold=c.drift_threshold,
-            time_per_token=c.time_per_token, factor_cache=c.factor_cache,
-            prefill_chunk=c.prefill_chunk, sampling=c.sampling,
-            nucleus=c.nucleus, top_k_cap=c.top_k_cap,
-            prefix_cache=c.prefix_cache, prefix_pages=c.prefix_pages,
-            speculative=c.speculative, draft_k=c.draft_k,
-            draft_rank_frac=c.draft_rank_frac,
-            snapshot_every=c.snapshot_every,
-            adaptive_draft=c.adaptive_draft,
-            draft_shrink_below=c.draft_shrink_below,
-            draft_grow_above=c.draft_grow_above,
-            record_traces=c.record_traces, obs_trace=c.obs_trace,
-            flight_dir=c.flight_dir, flight_capacity=c.flight_capacity)
+        self.device = device
+        if device is not None:
+            import jax
+            params = jax.device_put(params, device)
+        with self._placed():
+            self.core = ServeEngine(
+                cfg, params, policy_params,
+                n_slots=c.n_slots, max_len=c.max_len, page_size=c.page_size,
+                segment_len=c.segment_len, buckets=c.buckets,
+                max_new_cap=c.max_new_cap, use_kernel=c.use_kernel,
+                drift_threshold=c.drift_threshold,
+                time_per_token=c.time_per_token, factor_cache=c.factor_cache,
+                prefill_chunk=c.prefill_chunk, sampling=c.sampling,
+                nucleus=c.nucleus, top_k_cap=c.top_k_cap,
+                prefix_cache=c.prefix_cache, prefix_pages=c.prefix_pages,
+                speculative=c.speculative, draft_k=c.draft_k,
+                draft_rank_frac=c.draft_rank_frac,
+                snapshot_every=c.snapshot_every,
+                adaptive_draft=c.adaptive_draft,
+                draft_shrink_below=c.draft_shrink_below,
+                draft_grow_above=c.draft_grow_above,
+                record_traces=c.record_traces, obs_trace=c.obs_trace,
+                flight_dir=c.flight_dir, flight_capacity=c.flight_capacity)
         self._handles: Dict[int, RequestHandle] = {}
         self._next_rid = 0
         self._finished_seen = 0
@@ -403,6 +414,15 @@ class Engine:
         # request), which recurses on the same thread instead of
         # deadlocking.
         self._step_lock = threading.RLock()
+
+    def _placed(self):
+        """Context putting the arrays the core creates, and the steps it
+        dispatches, on this engine's device. Thread-local: it is entered
+        on whichever thread drives the engine."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        import jax
+        return jax.default_device(self.device)
 
     # -- request plane ---------------------------------------------------
 
@@ -458,7 +478,8 @@ class Engine:
             h = self._handles.get(rid)
             if h is None or h.done:
                 return False
-            self.core.cancel(rid)
+            with self._placed():
+                self.core.cancel(rid)
             h._mark_cancelled()   # sealed under the step lock: no feed races
             self._streaming.discard(rid)
             if not self._streaming:
@@ -492,7 +513,7 @@ class Engine:
     def warmup(self) -> float:
         # under the step lock: warmup touches the pools a concurrent
         # stepping thread would otherwise race
-        with self._step_lock:
+        with self._step_lock, self._placed():
             dt = self.core.warmup()
             # compile time is reported separately (stats['compile_s']); a
             # handle submitted before warmup should not charge it to TTFT
@@ -511,7 +532,7 @@ class Engine:
         external server loop. Thread-safe: handles on different threads
         (each blocking in ``result()``/``tokens()``) interleave whole
         iterations under one lock instead of racing the core state."""
-        with self._step_lock:
+        with self._step_lock, self._placed():
             stats = self.core.stats
             p0 = stats["prefill_s"]
             t0 = time.perf_counter()
@@ -571,7 +592,8 @@ class Engine:
                 self.core.obs.flight_dump("reset_with_live_requests")
             for h in self._handles.values():
                 h._mark_stopped()
-            self.core.reset()
+            with self._placed():
+                self.core.reset()
             self._handles.clear()
             self._finished_seen = 0
             self._streaming.clear()

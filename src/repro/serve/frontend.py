@@ -274,18 +274,23 @@ class Router:
     :class:`FleetConfig.routing`): each submit probes every replica's
     radix tree read-only for the prompt's longest cached prefix and
     routes to the warm replica when the reuse is worth it, otherwise to
-    the least-loaded. Replicas are data-parallel and independent — one
-    process here, but nothing in the dispatch path reads replica
+    the least-loaded. Replicas are data-parallel and independent, one
+    process driving them all: replica ``i`` lives on local device
+    ``i mod len(jax.local_devices())`` (params, page pools and control
+    state), so on a host with as many chips as replicas each replica has
+    a chip to itself. Nothing in the dispatch path reads replica
     internals other than ``depth`` and the prefix probe, both cheap and
-    lock-protected, so replicas can move behind a device/process
-    boundary without touching the fused step."""
+    lock-protected."""
 
     def __init__(self, cfg: ModelConfig, params, policy_params=None, *,
                  fleet: Optional[FleetConfig] = None):
+        import jax
         self.fleet = fleet or FleetConfig()
         f = self.fleet
+        devices = jax.local_devices()
         self.replicas: List[FrontEnd] = [
-            FrontEnd(Engine(cfg, params, policy_params, config=f.engine),
+            FrontEnd(Engine(cfg, params, policy_params, config=f.engine,
+                            device=devices[i % len(devices)]),
                      idle_poll_s=f.idle_poll_s, warmup=f.warmup,
                      name=f"serve-replica-{i}")
             for i in range(f.n_replicas)]

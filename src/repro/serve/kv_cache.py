@@ -104,6 +104,9 @@ class PagedKVCache:
         self._free: List[int] = list(range(self.n_pages - 1, 0, -1))  # not 0
         self.ref = np.zeros((self.n_pages,), np.int32)
         self.lens = np.zeros((n_slots,), np.int64)
+        # leading pages each slot was admitted onto already shared (a
+        # prefix hit): the slot never writes them (see shared_floor)
+        self.n_shared = np.zeros((n_slots,), np.int64)
         self.rank_on = cfg.rank.mode != "off"
         r_max = int(cfg.rank.rank_grid[-1]) if self.rank_on else dh
         self.r_keep = min(r_max, dh)
@@ -178,6 +181,7 @@ class PagedKVCache:
         self.page_table[slot, :] = 0
         self.page_table[slot, :need] = pages
         self.lens[slot] = 0
+        self.n_shared[slot] = len(prefix_pages)
         return True
 
     def release(self, slot: int) -> None:
@@ -187,11 +191,13 @@ class PagedKVCache:
         self.unref(int(p) for p in self.page_table[slot] if p != 0)
         self.page_table[slot, :] = 0
         self.lens[slot] = 0
+        self.n_shared[slot] = 0
 
     def shared_floor(self, slot: int) -> int:
-        """First logical position in ``slot`` whose page is private
-        (ref == 1): everything before it lives on pages shared with the
-        prefix tree or another slot and is immutable to this slot.
+        """First logical position of ``slot`` past the pages it was
+        admitted onto already shared (a prefix hit's leading run, still
+        held by the prefix tree or another slot): everything before it is
+        immutable to this slot.
 
         This is the rewind floor for speculative decoding: a draft/verify
         step writes (and a rejection logically rewinds, by not advancing
@@ -200,13 +206,13 @@ class PagedKVCache:
         strictly before the slot's first written position and a partial
         shared tail page is COWed at admission — so speculative writes at
         positions >= lens can never land on a shared page; the engine
-        asserts it per step rather than trusting the construction."""
-        floor = 0
-        for p in self.page_table[slot]:
-            if p == 0 or int(self.ref[p]) <= 1:
-                break
-            floor += self.page_size
-        return floor
+        asserts it per step rather than trusting the construction.
+
+        Pages the slot filled itself stay writable past its prompt even
+        once the prefix tree holds them too: the tree claims only the
+        prompt's positions, and a later hit that ends inside such a page
+        copies it first."""
+        return int(self.n_shared[slot]) * self.page_size
 
     def live_pages(self) -> Dict[int, List[int]]:
         """slot -> referenced physical pages (for invariant checks)."""

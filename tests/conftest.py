@@ -12,8 +12,6 @@ except ImportError:  # container has no hypothesis wheel — use the shim
 
 import jax
 
-import repro  # noqa: F401  (applies the jax forward-compat shim)
-
 jax.config.update("jax_enable_x64", False)
 
 # dist/slow markers are registered in pyproject.toml [tool.pytest.ini_options]

@@ -342,3 +342,75 @@ def test_fleet_drains_bursty_mixed_workload(setup):
         for fe in router.replicas:
             fe.engine.core.cache.check_refs(
                 fe.engine.core.prefix.all_pages())
+
+
+_ROUTER_4DEV = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import json, sys
+sys.path.insert(0, __SRC__)
+import jax
+import numpy as np
+from repro.configs import get_config
+from repro.configs.base import RankConfig
+from repro.models.api import get_model
+from repro.serve import EngineConfig, FleetConfig, Router, SamplingParams
+
+cfg = get_config("drrl-paper", reduced=True).with_(rank=RankConfig(
+    mode="adaptive", rank_grid=(4, 8, 12, 16), fixed_rank=8, segment_len=8))
+params = get_model(cfg).init(jax.random.PRNGKey(0))
+rnd = np.random.default_rng(0)
+prompts = [rnd.integers(0, 256, int(rnd.integers(8, 20))).astype(np.int32)
+           for _ in range(8)]
+
+def run(n):
+    router = Router(cfg, params, fleet=FleetConfig(
+        engine=EngineConfig(n_slots=2, max_len=48, page_size=8,
+                            segment_len=8, max_new_cap=8, prefill_chunk=8,
+                            prefix_cache=True),
+        n_replicas=n))
+    hs = [router.submit(p, SamplingParams(max_new=8)) for p in prompts]
+    outs = [h.result().tolist() for h in hs]
+    placed = []
+    for fe in router.replicas:
+        core = fe.engine.core
+        placed.append([str(d) for x in (core.params["embed"],
+                                        core.cache.k_pool, core.cache.v_pool,
+                                        core.cache.mass_pool, core.out_buf)
+                       for d in x.devices()])
+    router.shutdown()
+    return outs, placed, router.routed
+
+outs4, placed4, routed4 = run(4)
+outs1, _, _ = run(1)
+print(json.dumps({"n_dev": len(jax.local_devices()), "placed": placed4,
+                  "routed": routed4, "same": outs4 == outs1,
+                  "lens": [len(o) for o in outs4]}))
+"""
+
+
+@pytest.mark.dist
+def test_router_places_each_replica_on_its_own_device():
+    """On a 4-device host a 4-replica Router keeps each replica's params,
+    page pools and output buffer on its own device, and serves every
+    request exactly as a 1-replica Router does."""
+    import json
+    import os
+    import subprocess
+    import sys
+    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _ROUTER_4DEV.replace("__SRC__", repr(src))],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["n_dev"] == 4
+    assert all(len(set(p)) == 1 for p in res["placed"]), res["placed"]
+    assert len({p[0] for p in res["placed"]}) == 4, res["placed"]
+    assert all(n > 0 for n in res["routed"]), res["routed"]
+    assert res["lens"] == [8] * 8
+    assert res["same"]

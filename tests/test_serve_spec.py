@@ -207,6 +207,30 @@ def test_spec_rollback_with_live_prefix_hits():
     assert eng.cache.free_pages == eng.cache.n_pages - 1 - tree
 
 
+def test_spec_prefix_inserter_decodes_into_its_shared_tail_page():
+    """Prompts that end mid-page: once a prompt is in the prefix tree,
+    its own slot keeps decoding (speculatively) into the tail page the
+    tree now also holds — the tree claims only the prompt's positions, so
+    that is legal, and hits on it still match cold decode."""
+    cfg = _cfg("adaptive")
+    params = get_model(cfg).init(RNG)
+    rnd = np.random.default_rng(8)
+    shared = rnd.integers(0, cfg.vocab_size, 20).astype(np.int32)
+    prompts = [np.concatenate([shared,
+                               rnd.integers(0, cfg.vocab_size,
+                                            5).astype(np.int32)])
+               for _ in range(3)]
+    reqs = [dict(arrival=10 * i) for i in range(3)]
+    off, _ = _run(cfg, params, prompts, speculative=False, reqs=reqs)
+    on, eng = _run(cfg, params, prompts, speculative=True,
+                   prefix_cache=True, reqs=reqs)
+    for i in range(3):
+        np.testing.assert_array_equal(
+            on[i], off[i], err_msg=f"prefix-hit stream {i} diverged")
+    assert eng.stats["prefix_hits"] == 2
+    eng.cache.check_refs(eng.prefix.all_pages())
+
+
 def test_spec_cancel_mid_stream_leak_free():
     """Cancelling a stream between speculative steps releases its pages
     and stops delivery; the survivors finish with correct tokens."""
