@@ -73,6 +73,7 @@ ATTR_TARGETS: dict[str, tuple[str, str]] = {
     "obs.on_first_token": ("obs/core.py", "Observability.on_first_token"),
     "obs.on_finish": ("obs/core.py", "Observability.on_finish"),
     "obs.on_decide": ("obs/core.py", "Observability.on_decide"),
+    "obs.on_step": ("obs/core.py", "Observability.on_step"),
     "obs.on_drift": ("obs/core.py", "Observability.on_drift"),
     "obs.on_prefill_chunk": ("obs/core.py", "Observability.on_prefill_chunk"),
     "obs.on_spec_accept": ("obs/core.py", "Observability.on_spec_accept"),

@@ -389,7 +389,8 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
         raise ValueError("mass_defer and mass_pool are mutually exclusive: "
                          "deferred contributions are applied by the caller")
     dtype = nn.dt(cfg.dtype)
-    x = params["embed"][tokens].astype(dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(dtype)
     ns, C = tokens.shape
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
     dh = cfg.resolved_head_dim()
@@ -445,125 +446,135 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
         lp, kp, vp, basis_l, extra = xs
         ktp, mp = extra.get("kt"), extra.get("mass")
         p = lp["attn"]
-        h = nn.rms_norm(x, lp["ln1"], cfg.rms_eps)
-        q = jnp.einsum("bsd,dhf->bshf", h, p["wq"].reshape(d, hq, dh).astype(x.dtype))
-        k = jnp.einsum("bsd,dhf->bshf", h, p["wk"].reshape(d, hkv, dh).astype(x.dtype))
-        v = jnp.einsum("bsd,dhf->bshf", h, p["wv"].reshape(d, hkv, dh).astype(x.dtype))
-        if cfg.qkv_bias:
-            q = q + p["bq"].reshape(hq, dh).astype(x.dtype)
-            k = k + p["bk"].reshape(hkv, dh).astype(x.dtype)
-            v = v + p["bv"].reshape(hkv, dh).astype(x.dtype)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        kp = kp.at[phys, off].set(k.astype(kp.dtype))
-        vp = vp.at[phys, off].set(v.astype(vp.dtype))
-        vg = vp[page_table].reshape(ns, M, hkv, dh)
-        if rcfg.mode == "off" or slot_ranks is None:
-            kg = kp[page_table].reshape(ns, M, hkv, dh)
-            # stale page contents (freed + re-issued pages) must not leak:
-            # zero everything beyond the valid prefix
-            q_use = q
-            k_use = kg * valid[:, :, None, None].astype(kg.dtype)
+        with jax.named_scope("qkv"):
+            h = nn.rms_norm(x, lp["ln1"], cfg.rms_eps)
+            q = jnp.einsum("bsd,dhf->bshf", h, p["wq"].reshape(d, hq, dh).astype(x.dtype))
+            k = jnp.einsum("bsd,dhf->bshf", h, p["wk"].reshape(d, hkv, dh).astype(x.dtype))
+            v = jnp.einsum("bsd,dhf->bshf", h, p["wv"].reshape(d, hkv, dh).astype(x.dtype))
+            if cfg.qkv_bias:
+                q = q + p["bq"].reshape(hq, dh).astype(x.dtype)
+                k = k + p["bk"].reshape(hkv, dh).astype(x.dtype)
+                v = v + p["bv"].reshape(hkv, dh).astype(x.dtype)
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            kp = kp.at[phys, off].set(k.astype(kp.dtype))
+            vp = vp.at[phys, off].set(v.astype(vp.dtype))
+            if ktp is not None:
+                # factor-form cache: append the new tokens' factors under
+                # the slot's segment basis
+                kt_new = jnp.einsum("bshd,bhdr->bshr",
+                                    k.astype(jnp.float32), basis_l)
+                ktp = ktp.at[slot_rows, slot_pos].set(
+                    kt_new.astype(ktp.dtype))
+        rank_on = rcfg.mode != "off" and slot_ranks is not None
+        with jax.named_scope("kv_read"):
+            vg = vp[page_table].reshape(ns, M, hkv, dh)
+            if not rank_on or ktp is None or mixed:
+                kg = kp[page_table].reshape(ns, M, hkv, dh)
+                # stale page contents (freed + re-issued pages) must not
+                # leak: zero everything beyond the valid prefix
+                k_dense = kg * valid[:, :, None, None].astype(kg.dtype)
+            if ktp is not None:
+                # the slot-indexed factors: r/d of the dense K bytes
+                ktg = ktp[:ns]                        # (ns, M, hkv, r)
+                k_fac = (ktg * valid[:, :, None, None].astype(ktg.dtype)
+                         ).astype(x.dtype)
+        if not rank_on:
+            q_use, k_use = q, k_dense
         else:
             # project q onto the slot's cached segment eigenbasis; per-row
             # rank = zeroed q columns beyond the slot's bucket (the score
             # contraction then ignores the matching k-factor columns, so
             # the k side needs no mask)
-            b_q = (jnp.repeat(basis_l, n_rep, axis=1) if n_rep > 1
-                   else basis_l)                         # (ns, hq, d, r)
-            q_proj = (jnp.einsum("bshd,bhdr->bshr", q.astype(jnp.float32),
-                                 b_q)
-                      * col_ok[:, None, None, :]).astype(x.dtype)
-            if ktp is not None:
-                # factor-form cache: append the new tokens' factors and
-                # read the slot-indexed factors — r/d of the dense K bytes
-                kt_new = jnp.einsum("bshd,bhdr->bshr",
-                                    k.astype(jnp.float32), basis_l)
-                ktp = ktp.at[slot_rows, slot_pos].set(
-                    kt_new.astype(ktp.dtype))
-                ktg = ktp[:ns]                        # (ns, M, hkv, r)
-                k_fac = (ktg * valid[:, :, None, None].astype(ktg.dtype)
-                         ).astype(x.dtype)
-            else:
-                kg = kp[page_table].reshape(ns, M, hkv, dh)
-                k_masked = kg * valid[:, :, None, None].astype(kg.dtype)
-                k_fac = jnp.einsum("bmhd,bhdr->bmhr",
-                                   k_masked.astype(jnp.float32),
-                                   basis_l).astype(x.dtype)
+            with jax.named_scope("rank_proj"):
+                b_q = (jnp.repeat(basis_l, n_rep, axis=1) if n_rep > 1
+                       else basis_l)                     # (ns, hq, d, r)
+                q_proj = (jnp.einsum("bshd,bhdr->bshr",
+                                     q.astype(jnp.float32), b_q)
+                          * col_ok[:, None, None, :]).astype(x.dtype)
+                if ktp is None:
+                    k_fac = jnp.einsum("bmhd,bhdr->bmhr",
+                                       k_dense.astype(jnp.float32),
+                                       basis_l).astype(x.dtype)
             if not mixed:
                 q_use, k_use = q_proj, k_fac
             else:
                 # mid-prefill rows attend full-rank dense; decode rows
                 # keep the factor read. Pad the factor side to head-dim
                 # width (exact zeros) and select per row.
-                kg = kp[page_table].reshape(ns, M, hkv, dh)
-                k_dense = kg * valid[:, :, None, None].astype(kg.dtype)
-                pad = ((0, 0), (0, 0), (0, 0), (0, dh - r_keep))
-                q_use = jnp.where(is_pf[:, None, None, None], q,
-                                  jnp.pad(q_proj, pad))
-                k_use = jnp.where(is_pf[:, None, None, None], k_dense,
-                                  jnp.pad(k_fac, pad))
-        probs = None
-        want_probs = (mp is not None) or mass_defer
-        if use_kernel:
-            from repro.kernels.ops import decode_attention
-            qk = jnp.swapaxes(q_use, 1, 2)               # (ns, hq, C, r)
-            res = decode_attention(
-                qk if mixed or C > 1 else qk[:, :, 0],
-                jnp.swapaxes(k_use, 1, 2),               # (ns, hkv, M, r)
-                jnp.swapaxes(vg, 1, 2),                  # (ns, hkv, M, dh)
-                kv_end, scale=scale, q_start=slot_lens,
-                return_probs=want_probs)
-            if want_probs:
-                o, probs = res                       # probs (ns, hq, [C,] M)
+                with jax.named_scope("kv_read"):
+                    pad = ((0, 0), (0, 0), (0, 0), (0, dh - r_keep))
+                    q_use = jnp.where(is_pf[:, None, None, None], q,
+                                      jnp.pad(q_proj, pad))
+                    k_use = jnp.where(is_pf[:, None, None, None], k_dense,
+                                      jnp.pad(k_fac, pad))
+        with jax.named_scope("attend"):
+            probs = None
+            want_probs = (mp is not None) or mass_defer
+            if use_kernel:
+                from repro.kernels.ops import decode_attention
+                qk = jnp.swapaxes(q_use, 1, 2)               # (ns, hq, C, r)
+                res = decode_attention(
+                    qk if mixed or C > 1 else qk[:, :, 0],
+                    jnp.swapaxes(k_use, 1, 2),               # (ns, hkv, M, r)
+                    jnp.swapaxes(vg, 1, 2),                  # (ns, hkv, M, dh)
+                    kv_end, scale=scale, q_start=slot_lens,
+                    return_probs=want_probs)
+                if want_probs:
+                    o, probs = res                       # probs (ns, hq, [C,] M)
+                else:
+                    o = res
+                if o.ndim == 3:
+                    o, probs = o[:, :, None], (None if probs is None
+                                               else probs[:, :, None])
+                o = jnp.swapaxes(o, 1, 2)                    # (ns, C, hq, dh)
             else:
-                o = res
-            if o.ndim == 3:
-                o, probs = o[:, :, None], (None if probs is None
-                                           else probs[:, :, None])
-            o = jnp.swapaxes(o, 1, 2)                    # (ns, C, hq, dh)
-        else:
-            res = attend(q_use, repeat_kv(k_use, n_rep), repeat_kv(vg, n_rep),
-                         scale=scale, causal=False,
-                         kv_len=kv_len_q[:, None, :, None],
-                         score_dtype=score_dtype,
-                         return_probs=want_probs)
-            if want_probs:
-                o, probs = res                           # probs (ns, hq, C, M)
-            else:
-                o = res
-        if mp is not None:
-            # per-key attention mass: group-mean over each kv head's q
-            # heads, masked to live lanes and valid queries (dead lanes /
-            # padding columns contribute exact zeros, so the slot-indexed
-            # accumulate is a plain add — no scatter, no scratch row).
-            # Cells appended this step are reset before the add, so a
-            # recycled slot's stale mass dies the moment the position is
-            # reused — no eager pool-wide zeroing at admission.
-            from repro.models.common import kv_group_mean
-            w = (probs.astype(jnp.float32)
-                 * write_ok[:, None, :, None]).sum(axis=2)   # (ns, hq, M)
-            w_tok = kv_group_mean(w, hkv)                    # (ns, hkv, M)
-            mp = (jnp.where(new_cell[:, :, None], 0.0, mp)
-                  + jnp.swapaxes(w_tok, 1, 2).astype(mp.dtype))
+                res = attend(q_use, repeat_kv(k_use, n_rep), repeat_kv(vg, n_rep),
+                             scale=scale, causal=False,
+                             kv_len=kv_len_q[:, None, :, None],
+                             score_dtype=score_dtype,
+                             return_probs=want_probs)
+                if want_probs:
+                    o, probs = res                           # probs (ns, hq, C, M)
+                else:
+                    o = res
         mass_q = None
-        if mass_defer:
-            # per-query mass, NOT summed over the chunk: the caller masks
-            # to the accepted queries before applying (spec verify)
-            from repro.models.common import kv_group_mean
-            wq = (probs.astype(jnp.float32)
-                  * write_ok[:, None, :, None])              # (ns, hq, C, M)
-            wq = kv_group_mean(jnp.swapaxes(wq, 1, 2), hkv)  # (ns, C, hkv, M)
-            mass_q = jnp.swapaxes(wq, 2, 3)                  # (ns, C, M, hkv)
-        x = x + jnp.einsum("bshf,hfd->bsd", o,
-                           p["wo"].reshape(hq, dh, d).astype(x.dtype))
-        if cfg.family == "moe" and cfg.moe is not None and "moe" in lp:
-            f, _ = moe_mod.moe_ffn(cfg, lp["moe"],
-                                   nn.rms_norm(x, lp["ln2"], cfg.rms_eps))
-        else:
-            f = nn.swiglu(nn.rms_norm(x, lp["ln2"], cfg.rms_eps),
-                          lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
-                          lp["ffn"]["w_down"])
+        with jax.named_scope("mass"):
+            if mp is not None:
+                # per-key attention mass: group-mean over each kv head's q
+                # heads, masked to live lanes and valid queries (dead lanes /
+                # padding columns contribute exact zeros, so the slot-indexed
+                # accumulate is a plain add — no scatter, no scratch row).
+                # Cells appended this step are reset before the add, so a
+                # recycled slot's stale mass dies the moment the position is
+                # reused — no eager pool-wide zeroing at admission.
+                from repro.models.common import kv_group_mean
+                w = (probs.astype(jnp.float32)
+                     * write_ok[:, None, :, None]).sum(axis=2)   # (ns, hq, M)
+                w_tok = kv_group_mean(w, hkv)                    # (ns, hkv, M)
+                mp = (jnp.where(new_cell[:, :, None], 0.0, mp)
+                      + jnp.swapaxes(w_tok, 1, 2).astype(mp.dtype))
+            if mass_defer:
+                # per-query mass, NOT summed over the chunk: the caller masks
+                # to the accepted queries before applying (spec verify)
+                from repro.models.common import kv_group_mean
+                wq = (probs.astype(jnp.float32)
+                      * write_ok[:, None, :, None])              # (ns, hq, C, M)
+                wq = kv_group_mean(jnp.swapaxes(wq, 1, 2), hkv)  # (ns, C, hkv, M)
+                mass_q = jnp.swapaxes(wq, 2, 3)                  # (ns, C, M, hkv)
+        with jax.named_scope("out_proj"):
+            x = x + jnp.einsum("bshf,hfd->bsd", o,
+                               p["wo"].reshape(hq, dh, d).astype(x.dtype))
+        with jax.named_scope("ffn"):
+            if cfg.family == "moe" and cfg.moe is not None and "moe" in lp:
+                f, _ = moe_mod.moe_ffn(cfg, lp["moe"],
+                                       nn.rms_norm(x, lp["ln2"], cfg.rms_eps))
+            else:
+                f = nn.swiglu(nn.rms_norm(x, lp["ln2"], cfg.rms_eps),
+                              lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
+                              lp["ffn"]["w_down"])
+            x = x + f
         new_extra = {}
         if ktp is not None:
             new_extra["kt"] = ktp
@@ -571,27 +582,30 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
             new_extra["mass"] = mp
         if mass_q is not None:
             new_extra["mass_q"] = mass_q
-        return x + f, (kp, vp, new_extra)
+        return x, (kp, vp, new_extra)
 
     from repro.models.common import scan_or_unroll
-    basis_xs = (basis if basis is not None else
-                jnp.zeros((cfg.num_layers, ns, hkv, dh, 1), jnp.float32))
-    extra_xs = {}
-    if kt_pool is not None:
-        extra_xs["kt"] = kt_pool
-    if mass_pool is not None:
-        extra_xs["mass"] = mass_pool
-    x, (nk, nv, n_extra) = scan_or_unroll(
-        body, x, (params["layers"], pool_k, pool_v, basis_xs, extra_xs),
-        unroll=not cfg.scan_layers)
-    if C > 1 and not return_all_logits:
-        # only each row's last valid query feeds the LM head: the next
-        # token for decode rows, token 0 for a row finishing its prompt
-        x = jnp.take_along_axis(x, (q_lens - 1)[:, None, None], axis=1)
-    x = nn.rms_norm(x, params["ln_f"], cfg.rms_eps)
-    head = params.get("lm_head", None)
-    logits = (jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-              if head is not None else
-              jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype)))
+    with jax.named_scope("layers"):
+        basis_xs = (basis if basis is not None else
+                    jnp.zeros((cfg.num_layers, ns, hkv, dh, 1), jnp.float32))
+        extra_xs = {}
+        if kt_pool is not None:
+            extra_xs["kt"] = kt_pool
+        if mass_pool is not None:
+            extra_xs["mass"] = mass_pool
+        x, (nk, nv, n_extra) = scan_or_unroll(
+            body, x, (params["layers"], pool_k, pool_v, basis_xs, extra_xs),
+            unroll=not cfg.scan_layers)
+    with jax.named_scope("head"):
+        if C > 1 and not return_all_logits:
+            # only each row's last valid query feeds the LM head: the next
+            # token for decode rows, token 0 for a row finishing its prompt
+            x = jnp.take_along_axis(x, (q_lens - 1)[:, None, None], axis=1)
+        x = nn.rms_norm(x, params["ln_f"], cfg.rms_eps)
+        head = params.get("lm_head", None)
+        logits = (jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+                  if head is not None else
+                  jnp.einsum("bsd,vd->bsv", x,
+                             params["embed"].astype(x.dtype)))
     pools = {"k": nk, "v": nv, **n_extra}
     return logits, pools

@@ -10,7 +10,9 @@ Four pieces, one facade:
   registry.
 * :mod:`repro.obs.tracing` — per-request span traces and the per-step
   phase timeline as Chrome trace-event JSON (Perfetto-loadable), plus
-  the schema validator and the shared ``Stopwatch`` timing helper.
+  the schema validator and the shared ``Stopwatch`` timing helper. The
+  step phases' seconds are always counted (``serve.phase.<p>_s``) and
+  always annotated for the JAX profiler (``serve.<p>``).
 * :mod:`repro.obs.flight` — a bounded ring of recent events dumped to
   disk on step exceptions / ``EngineStopped`` / front-end shutdown.
 * :mod:`repro.obs.core` — :class:`Observability`, the per-engine bundle
@@ -23,8 +25,8 @@ from repro.obs.core import Observability
 from repro.obs.flight import FLIGHT_SCHEMA_VERSION, FlightRecorder
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                StatsView, aggregate, aggregate_registry)
-from repro.obs.tracing import (NULL_PHASES, PHASES, SpanTracer, StepPhases,
-                               Stopwatch, validate_chrome_trace)
+from repro.obs.tracing import (PHASES, SpanTracer, StepPhases, Stopwatch,
+                               validate_chrome_trace)
 
 __all__ = [
     "Observability",
@@ -37,7 +39,6 @@ __all__ = [
     "StatsView",
     "aggregate",
     "aggregate_registry",
-    "NULL_PHASES",
     "PHASES",
     "SpanTracer",
     "StepPhases",

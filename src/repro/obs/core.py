@@ -28,8 +28,7 @@ from typing import Any, Dict, List, Optional
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import (DEFAULT_COUNT_BUCKETS, MetricsRegistry,
                                StatsView)
-from repro.obs.tracing import (NULL_PHASES, PHASES, SpanTracer, StepPhases,
-                               Stopwatch)
+from repro.obs.tracing import PHASES, SpanTracer, StepPhases, Stopwatch
 
 __all__ = ["Observability", "Stopwatch"]
 
@@ -37,10 +36,10 @@ _ENGINE_SEQ = itertools.count()
 
 
 class Observability:
-    """Per-engine observability bundle. Always constructed (the registry
-    and flight ring are cheap and always on); span/phase tracing is
-    opt-in via ``trace=True`` because it allocates an event per step
-    phase and per request milestone."""
+    """Per-engine observability bundle. Always constructed (the registry,
+    the per-phase step seconds and the flight ring are cheap and always
+    on); span/phase tracing is opt-in via ``trace=True`` because it
+    allocates an event per step phase and per request milestone."""
 
     def __init__(self, *, trace: bool = False, trace_capacity: int = 200_000,
                  flight_dir: Optional[str] = None,
@@ -61,20 +60,22 @@ class Observability:
         self.latency_hist = r.histogram("serve.token_latency_s")
         self.accept_hist = r.histogram("serve.accept_len",
                                        bounds=DEFAULT_COUNT_BUCKETS)
-        self._phase_hists = ({p: r.histogram(f"serve.phase.{p}_s")
-                              for p in PHASES} if trace else None)
+        # seconds spent in each step phase, summed over steps; also in
+        # the engine's ``stats`` view as ``phase.<p>_s``
+        self._phase_s = {p: r.counter(f"serve.phase.{p}_s", init=0.0)
+                         for p in PHASES}
         # request + rank control-plane counters (the per-step token/stat
         # counters live behind the engine's StatsView — same registry)
         self._c_admitted = r.counter("requests.admitted")
         self._c_finished = r.counter("requests.finished")
         self._c_cancelled = r.counter("requests.cancelled")
         self._c_decisions = r.counter("rank.decisions")
-        self._c_refreshes = r.counter("rank.basis_refreshes")
         self._c_forced = r.counter("rank.forced_decides")
         self._c_drift = r.counter("rank.drift_triggers")
         self._c_veto = r.counter("rank.veto_fires")
         self._g_queue = r.gauge("queue.depth")
         self._g_live = r.gauge("slots.live")
+        self._g_kv = r.gauge("kv.positions_live")
         self._g_prefix_nodes = r.gauge("prefix.nodes")
         self._g_prefix_pages = r.gauge("prefix.pages")
 
@@ -83,7 +84,9 @@ class Observability:
     def stats_view(self, init: Dict[str, Any],
                    gauges=("eff_draft_k",)) -> StatsView:
         """The engine's legacy ``stats`` surface as a registry view (and
-        the reset path: re-binding zeroes the backing metrics)."""
+        the reset path: re-binding zeroes the backing metrics), with the
+        step-phase seconds ``phase.<p>_s`` added."""
+        init = {**init, **{f"phase.{p}_s": 0.0 for p in PHASES}}
         return StatsView(self.registry, init, prefix="serve", gauges=gauges)
 
     def reset_run(self) -> None:
@@ -92,20 +95,16 @@ class Observability:
         if self.tracer is not None:
             self.tracer.clear()
 
-    def step_phases(self, step: int):
-        """Phase recorder for one step; a shared no-op when tracing is
-        off so the loop pays one attribute check per step."""
-        if self.tracer is None:
-            return NULL_PHASES
-        return StepPhases(self.tracer, step, self._phase_hists)
+    def step_phases(self, step: int) -> StepPhases:
+        """Phase recorder for one step: two clock reads, one add and one
+        profiler annotation per phase, plus an X event when tracing."""
+        return StepPhases(self._phase_s, step, self.tracer)
 
     # -- hooks (host values only; called from the step loop) --------------
 
     def on_admit(self, rid: int, slot: int, prompt_len: int, *,
-                 reused: int = 0, queued: int = 0, live: int = 0) -> None:
+                 reused: int = 0) -> None:
         self._c_admitted.inc()
-        self._g_queue.set(queued)
-        self._g_live.set(live)
         self.flight.record("admit", rid=rid, slot=slot,
                            prompt_len=prompt_len, reused=reused)
         if self.tracer is not None:
@@ -135,7 +134,6 @@ class Observability:
     def on_decide(self, slot: int, seg_t: int, *,
                   forced: bool = False) -> None:
         self._c_decisions.inc()
-        self._c_refreshes.inc()   # every decision refreshes the basis
         if forced:
             self._c_forced.inc()
         self.flight.record("decide", slot=slot, seg_t=seg_t,
@@ -144,6 +142,13 @@ class Observability:
             self.tracer.instant("rank_decide", tid=slot, cat="rank",
                                 args={"slot": slot, "seg_t": seg_t,
                                       "forced": forced})
+
+    def on_step(self, *, queued: int, live: int, kv_positions: int) -> None:
+        """End of a step: requests waiting, slots in use and the cache
+        positions they hold (prompt tokens prefilled + tokens out)."""
+        self._g_queue.set(queued)
+        self._g_live.set(live)
+        self._g_kv.set(kv_positions)
 
     def on_drift(self, slot: int, drift: float) -> None:
         self._c_drift.inc()
@@ -266,7 +271,8 @@ class Observability:
             "mean_kept_rank": mean_rank,
             "rank_switches": switches,
             "veto_fires": veto,
-            "basis_refreshes": self._c_refreshes.value,
+            # every decision refreshes the basis
+            "basis_refreshes": self._c_decisions.value,
             "drift_triggers": self._c_drift.value,
             "decisions": self._c_decisions.value,
             "read_bytes_per_token": (float(np.mean(read_bpt))

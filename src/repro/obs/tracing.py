@@ -12,14 +12,21 @@ Two timelines share one event buffer:
   between "the fused step was dispatched" and "tokens were delivered"
   is visible per step in Perfetto.
 
+The phases are timed whether or not a tracer is attached
+(:class:`StepPhases`): each adds its seconds to an always-on registry
+counter (``serve.phase.<phase>_s``) and runs inside a
+``jax.profiler.TraceAnnotation("serve.<phase>")``, so a profiler capture
+holds the phases on the device ops' own clock. A tracer adds the X events.
+
 The output of :meth:`SpanTracer.chrome_trace` is the stable Chrome
 trace-event format (``{"traceEvents": [...]}``): load it at
 https://ui.perfetto.dev or chrome://tracing. :func:`validate_chrome_trace`
 checks a document against the subset of the schema this module emits —
 the bench/CI path validates every emitted trace before upload.
 
-Everything here is host-side Python over ``time.perf_counter`` — no jax
-calls, so tracing cannot introduce device syncs or recompiles (the
+Everything here is host-side Python over ``time.perf_counter``; the one
+jax call, ``TraceAnnotation``, is a host marker that only a running
+profiler records. Tracing cannot introduce device syncs or recompiles (the
 sanitizer's ``observability`` scenario pins that).
 """
 from __future__ import annotations
@@ -27,11 +34,15 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # step phases, in loop order. "schedule" covers eviction + slot harvest,
 # "admit" the admission/prefill work, "decide" rank re-decisions +
 # control-state sync, "dispatch" the fused step call, "fetch" the
 # sanctioned host fetches, "deliver" per-slot host bookkeeping/streaming.
 PHASES = ("schedule", "admit", "decide", "dispatch", "fetch", "deliver")
+# the profiler annotation each phase runs inside
+PHASE_ANNOTATIONS = {p: f"serve.{p}" for p in PHASES}
 
 _VALID_PH = {"X", "B", "E", "b", "e", "n", "i", "I", "C", "M"}
 
@@ -71,15 +82,22 @@ class SpanTracer:
         self.capacity = capacity
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0
-        self._t0 = time.perf_counter()
+        # perf_counter seconds at ts 0: event times map back to the host
+        # clock as origin_s + ts * 1e-6
+        self.origin_s = time.perf_counter()
+
+    @property
+    def _t0(self) -> float:
+        """``origin_s`` under its former name (chipbench/run.py reads it)."""
+        return self.origin_s
 
     def now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return (time.perf_counter() - self.origin_s) * 1e6
 
     def clear(self) -> None:
         self.events = []
         self.dropped = 0
-        self._t0 = time.perf_counter()
+        self.origin_s = time.perf_counter()
 
     def _push(self, ev: Dict[str, Any]) -> None:
         if len(self.events) >= self.capacity:
@@ -121,13 +139,6 @@ class SpanTracer:
         if args:
             ev["args"] = args
         self._push(ev)
-
-    def counter(self, name: str, values: Dict[str, float],
-                *, ts_us: Optional[float] = None) -> None:
-        self._push({"name": name, "ph": "C", "cat": "metric",
-                    "pid": self.pid, "tid": 0,
-                    "ts": self.now_us() if ts_us is None else ts_us,
-                    "args": dict(values)})
 
     def chrome_trace(self,
                      metadata: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
@@ -192,67 +203,45 @@ def validate_chrome_trace(doc: Any) -> List[str]:
     return errs
 
 
-class _NullCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-class _NullPhases:
-    """Phase recorder used when tracing is off: ``ph("decide")`` costs
-    one attribute call and returns a shared no-op context manager — the
-    engine's hot loop pays nothing measurable for the instrumentation
-    points."""
-
-    __slots__ = ()
-    _ctx = _NullCtx()
-
-    def __call__(self, name: str) -> _NullCtx:
-        return self._ctx
-
-
-NULL_PHASES = _NullPhases()
-
-
 class _PhaseCtx:
-    __slots__ = ("sp", "name", "t0")
+    __slots__ = ("sp", "name", "ann", "t0")
 
     def __init__(self, sp: "StepPhases", name: str):
         self.sp = sp
         self.name = name
+        self.ann = TraceAnnotation(PHASE_ANNOTATIONS[name])
         self.t0 = 0.0
 
     def __enter__(self):
-        self.t0 = self.sp.tracer.now_us()
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        sp, t1 = self.sp, self.sp.tracer.now_us()
-        dur = t1 - self.t0
-        sp.tracer.complete(self.name, self.t0, dur, tid=sp.tid,
-                           cat="phase", args={"step": sp.step})
-        h = sp.hists.get(self.name) if sp.hists else None
-        if h is not None:
-            h.observe(dur * 1e-6)
+        dt = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        sp = self.sp
+        sp.seconds[self.name].value += dt
+        tr = sp.tracer
+        if tr is not None:
+            tr.complete(self.name, (self.t0 - tr.origin_s) * 1e6, dt * 1e6,
+                        tid=sp.tid, cat="phase", args={"step": sp.step})
         return False
 
 
 class StepPhases:
-    """Live phase recorder for ONE engine step: each ``with ph(name):``
-    block becomes a complete event on the step lane plus an observation
-    in that phase's duration histogram."""
+    """Phase recorder for ONE engine step: each ``with ph(name):`` block
+    adds its seconds to ``seconds[name]`` (a registry counter) inside a
+    profiler annotation ``serve.<name>``, and, with a tracer, becomes a
+    complete event on the step lane."""
 
-    __slots__ = ("tracer", "step", "hists", "tid")
+    __slots__ = ("seconds", "step", "tracer", "tid")
 
-    def __init__(self, tracer: SpanTracer, step: int,
-                 hists: Optional[Dict[str, Any]] = None, *, tid: int = 1000):
-        self.tracer = tracer
+    def __init__(self, seconds: Dict[str, Any], step: int,
+                 tracer: Optional[SpanTracer] = None, *, tid: int = 1000):
+        self.seconds = seconds
         self.step = step
-        self.hists = hists
+        self.tracer = tracer
         self.tid = tid
 
     def __call__(self, name: str) -> _PhaseCtx:

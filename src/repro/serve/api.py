@@ -619,6 +619,11 @@ class Engine:
         (metrics registry, span tracer, flight recorder, exporters)."""
         return self.core.obs
 
+    def compiled_text(self) -> Dict[str, str]:
+        """See :meth:`repro.serve.engine.ServeEngine.compiled_text`."""
+        with self._step_lock:
+            return self.core.compiled_text()
+
     @property
     def depth(self) -> int:
         """Requests in the system (queued + admitted) — the router's

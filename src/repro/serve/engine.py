@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.api import get_model
-from repro.obs import NULL_PHASES, Observability, Stopwatch
+from repro.obs import Observability, Stopwatch
 from repro.serve.kv_cache import PagedKVCache
 from repro.serve.policy import basis_drift, make_decide_fn
 from repro.serve.prefix import MatchResult, PrefixCache
@@ -499,6 +499,39 @@ class ServeEngine:
         self.stats["compile_s"] += dt
         return dt
 
+    def compiled_text(self) -> Dict[str, str]:
+        """``{HLO module name: optimized HLO text}`` of the fused steps and
+        the segment decision this engine dispatches, for attributing a
+        profiler trace's device ops to the ``jax.named_scope`` names in
+        their ``op_name`` metadata. Call after :meth:`warmup`: lowering
+        with the arguments' current shapes finds the executables jit
+        already compiled, so nothing is compiled again."""
+        ns = self.n_slots
+        fused = (self.params, self.cache.k_pool, self.cache.v_pool,
+                 self.cache.kt_pool, self.cache.mass_pool, self._pt_dev,
+                 self.tokens, self._lens_dev, self.cache.ranks,
+                 self.cache.basis, self._active_dev, self.out_buf,
+                 self._plen_dev, self._temp_dev, self._topk_dev,
+                 self._topp_dev, self._seed_dev)
+        calls = [(self._step, fused),
+                 (self._step_mixed, fused + (self.prompt_buf,)),
+                 (self._step_spec, fused + (
+                     self.prompt_buf, self.cache.spectra,
+                     jnp.ones((ns,), jnp.int32), self._eos_dev)),
+                 (self._decide, (
+                     self.cache.k_pool, self.cache.mass_pool,
+                     self.cache.kt_pool, self._pt_dev, self._lens_dev,
+                     self.cache.ranks, self.cache.basis, self.cache.spectra,
+                     np.int32(0), np.bool_(False), np.int32(0)))]
+        if self.speculative:
+            calls.pop(0)        # the plain step is never warmed (see step())
+        out = {}
+        for fn, args in calls:
+            if fn is not None:
+                text = fn.lower(*args).compile().as_text()
+                out[text.split(",", 1)[0].split()[-1]] = text
+        return out
+
     # -- data plane ------------------------------------------------------
 
     def _prefill_impl(self, params, tokens, q_len):
@@ -573,13 +606,14 @@ class ServeEngine:
             use_kernel=self.use_kernel,
             kt_pool=None if off else kt_pool,
             mass_pool=None if off else mass_pool)
-        out_idx = jnp.where(active, jnp.minimum(lens - prompt_lens + 1,
-                                                self.max_new_cap - 1), 0)
-        tok = self._select_token(logits[:, 0], out_idx,
-                                 temps, topks, topps, seeds)[:, None]
-        tok = jnp.where(active[:, None], tok, tokens)
-        row = jnp.where(active, jnp.arange(ns), ns)       # dead -> scratch row
-        out_buf = out_buf.at[row, out_idx].set(tok[:, 0])
+        with jax.named_scope("sample"):
+            out_idx = jnp.where(active, jnp.minimum(
+                lens - prompt_lens + 1, self.max_new_cap - 1), 0)
+            tok = self._select_token(logits[:, 0], out_idx,
+                                     temps, topks, topps, seeds)[:, None]
+            tok = jnp.where(active[:, None], tok, tokens)
+            row = jnp.where(active, jnp.arange(ns), ns)   # dead -> scratch row
+            out_buf = out_buf.at[row, out_idx].set(tok[:, 0])
         lens = lens + active.astype(lens.dtype)
         return pools, tok, out_buf, lens
 
@@ -612,13 +646,14 @@ class ServeEngine:
         lens_after = lens + jnp.where(active, q_lens, 0)
         finishing = is_pf & (lens_after >= prompt_lens)
         emit = active & (finishing | ~is_pf)
-        out_idx = jnp.where(emit, jnp.clip(lens_after - prompt_lens, 0,
-                                           self.max_new_cap - 1), 0)
-        tok = self._select_token(logits[:, 0], out_idx,
-                                 temps, topks, topps, seeds)[:, None]
-        tok = jnp.where(emit[:, None], tok, tokens)
-        row = jnp.where(emit, jnp.arange(ns), ns)         # no-emit -> scratch
-        out_buf = out_buf.at[row, out_idx].set(tok[:, 0])
+        with jax.named_scope("sample"):
+            out_idx = jnp.where(emit, jnp.clip(lens_after - prompt_lens, 0,
+                                               self.max_new_cap - 1), 0)
+            tok = self._select_token(logits[:, 0], out_idx,
+                                     temps, topks, topps, seeds)[:, None]
+            tok = jnp.where(emit[:, None], tok, tokens)
+            row = jnp.where(emit, jnp.arange(ns), ns)     # no-emit -> scratch
+            out_buf = out_buf.at[row, out_idx].set(tok[:, 0])
         return pools, tok, out_buf, lens_after
 
     def _step_spec_impl(self, params, pool_k, pool_v, kt_pool, mass_pool,
@@ -680,9 +715,10 @@ class ServeEngine:
                 kt_pool=d_kt, mass_pool=None)
             pk, pv = dpools["k"], dpools["v"]
             d_kt = dpools.get("kt", d_kt)
-            opos = jnp.minimum(base_out + i, cap - 1)
-            d_tok = self._select_token(dlg[:, 0], opos,
-                                       temps, topks, topps, seeds)[:, None]
+            with jax.named_scope("sample"):
+                opos = jnp.minimum(base_out + i, cap - 1)
+                d_tok = self._select_token(dlg[:, 0], opos, temps, topks,
+                                           topps, seeds)[:, None]
             drafts.append(d_tok[:, 0])
         drafts = jnp.stack(drafts, axis=1)                   # (ns, k_d)
 
@@ -717,9 +753,10 @@ class ServeEngine:
                          jnp.minimum(base_out[:, None]
                                      + jnp.arange(C)[None, :],
                                      cap - 1))                   # (ns, C)
-        g = jax.vmap(self._select_token,
-                     in_axes=(1, 1, None, None, None, None),
-                     out_axes=1)(logits, opos, temps, topks, topps, seeds)
+        with jax.named_scope("sample"):
+            g = jax.vmap(self._select_token,
+                         in_axes=(1, 1, None, None, None, None),
+                         out_axes=1)(logits, opos, temps, topks, topps, seeds)
 
         tgt = g[:, :Cd]
         a = spec_mod.accept_counts(drafts, tgt)
@@ -737,7 +774,8 @@ class ServeEngine:
         rows = jnp.where(emit_ok, jnp.arange(ns)[:, None], ns)
         col0 = jnp.where(finishing, 0, base_out)
         cols = jnp.clip(col0[:, None] + jnp.arange(Cd)[None, :], 0, cap - 1)
-        out_buf = out_buf.at[rows, cols].set(src)
+        with jax.named_scope("sample"):
+            out_buf = out_buf.at[rows, cols].set(src)
         last = jnp.take_along_axis(
             src, jnp.clip(n_emit - 1, 0, Cd - 1)[:, None], axis=1)
         tok = jnp.where(n_emit[:, None] > 0, last, tokens)
@@ -869,13 +907,9 @@ class ServeEngine:
                 m = self._apply_prefix_hit(slot, req)
                 self._snaps[slot] = {}
                 self.stats["prefill_tokens"] += st.prompt_len - m
-                self.obs.on_admit(req.rid, slot, st.prompt_len, reused=m,
-                                  queued=len(self.sched.pending),
-                                  live=self.sched.n_live())
+                self.obs.on_admit(req.rid, slot, st.prompt_len, reused=m)
                 continue
-            self.obs.on_admit(req.rid, slot, st.prompt_len,
-                              queued=len(self.sched.pending),
-                              live=self.sched.n_live())
+            self.obs.on_admit(req.rid, slot, st.prompt_len)
             sw = Stopwatch()
             s = len(req.tokens)
             padded = np.zeros((1, bucket), np.int32)
@@ -1048,10 +1082,9 @@ class ServeEngine:
         self.obs.set_prefix_size(
             self.prefix.n_nodes, len(set(self.prefix.all_pages())))
 
-    def _step_live_spec(self, live: List[int], ph=NULL_PHASES) -> None:
+    def _step_live_spec(self, live: List[int], ph) -> None:
         """Host side of one speculative engine iteration (the fused body
-        is _step_spec_impl). ``ph`` is the step's phase recorder (a no-op
-        unless obs tracing is on). Differs from the plain path in three ways:
+        is _step_spec_impl). ``ph`` is the step's phase recorder. Differs from the plain path in three ways:
         decode rows advance by their accepted run length ``a`` (1..
         draft_k + 1) instead of 1; the per-step accept/emission fetch IS
         the token stream (handles get every accepted token, not just the
@@ -1317,6 +1350,10 @@ class ServeEngine:
                 self._check_drift(decoding)
             self._evict_finished()
         self.now += 1
+        live = [st for st in self.sched.slots if st.active]
+        self.obs.on_step(queued=len(self.sched.pending), live=len(live),
+                         kv_positions=sum(st.prefilled + st.n_out
+                                          for st in live))
 
     def run(self, max_steps: Optional[int] = None) -> Dict:
         """Drive the loop until every request finished. Returns

@@ -111,13 +111,14 @@ def make_decide_fn(cfg: ModelConfig, policy_params=None) -> Callable:
         # occupant's rank (the drrl feature path reads it even though the
         # veto is disabled): fall back to the fresh-slot default r_max
         prev_rank = jnp.where(has_rank, prev_rank, jnp.int32(g_hi))
-        gathered = k_pool[:, pt_row]           # (L, pages, ps, h, d)
-        L = gathered.shape[0]
-        kv = gathered.reshape(L, -1, *gathered.shape[3:])
-        M = kv.shape[1]
-        valid = (jnp.arange(M) < kv_len).astype(jnp.float32)
-        kk = jnp.swapaxes(kv, 1, 2).astype(jnp.float32) \
-            * valid[None, None, :, None]                  # (L, h, M, d)
+        with jax.named_scope("gather_k"):
+            gathered = k_pool[:, pt_row]       # (L, pages, ps, h, d)
+            L = gathered.shape[0]
+            kv = gathered.reshape(L, -1, *gathered.shape[3:])
+            M = kv.shape[1]
+            valid = (jnp.arange(M) < kv_len).astype(jnp.float32)
+            kk = jnp.swapaxes(kv, 1, 2).astype(jnp.float32) \
+                * valid[None, None, :, None]              # (L, h, M, d)
         # softmax-weighted Gram: w is the accumulated per-key attention
         # mass, normalised to sum kv_len so the spectra stay on the plain
         # Gram's scale (weights 1 per key); zero mass (state written
@@ -125,15 +126,17 @@ def make_decide_fn(cfg: ModelConfig, policy_params=None) -> Callable:
         # mass is slot-indexed (per-stream state, not per-page — shared
         # prefix pages receive different mass from each sharing slot), so
         # the gather is a plain row slice, no page indirection
-        w_row = jax.lax.dynamic_slice_in_dim(mass_pool, slot, 1, 1)[:, 0]
-        w = jnp.swapaxes(w_row, 1, 2)                     # (L, h, M)
-        w = jnp.maximum(w, 0.0) * valid[None, None, :]    # (L, h, M)
-        tot = jnp.sum(w, axis=-1, keepdims=True)
-        n_valid = jnp.maximum(kv_len.astype(jnp.float32), 1.0)
-        w = jnp.where(tot > 0.0, w * n_valid / jnp.maximum(tot, 1e-30),
-                      valid[None, None, :])
-        gk = jnp.einsum("lhmd,lhm,lhme->lhde", kk, w, kk)
-        s2_l, evecs_l = lr.gram_spectrum(gk)              # (L, h, d[, d])
+        with jax.named_scope("gram"):
+            w_row = jax.lax.dynamic_slice_in_dim(mass_pool, slot, 1, 1)[:, 0]
+            w = jnp.swapaxes(w_row, 1, 2)                     # (L, h, M)
+            w = jnp.maximum(w, 0.0) * valid[None, None, :]    # (L, h, M)
+            tot = jnp.sum(w, axis=-1, keepdims=True)
+            n_valid = jnp.maximum(kv_len.astype(jnp.float32), 1.0)
+            w = jnp.where(tot > 0.0, w * n_valid / jnp.maximum(tot, 1e-30),
+                          valid[None, None, :])
+            gk = jnp.einsum("lhmd,lhm,lhme->lhde", kk, w, kk)
+        with jax.named_scope("eigh"):
+            s2_l, evecs_l = lr.gram_spectrum(gk)          # (L, h, d[, d])
         s2 = s2_l[0]                 # layer-0 spectra drive the decision
         h = s2.shape[0]
         eps_t = pert.annealed_threshold(rcfg.epsilon0, rcfg.anneal_lambda, t)
@@ -208,10 +211,12 @@ def make_decide_fn(cfg: ModelConfig, policy_params=None) -> Callable:
             # depend on this slot's basis, so a shared prefix page's keys
             # are re-projected into the slot's OWN row, never into state
             # another slot reads
-            kt = jnp.einsum("lhmd,lhdr->lmhr", kk, evecs_l[..., :r_keep])
-            kt_pool = jax.lax.dynamic_update_slice(
-                kt_pool, kt[:, None].astype(kt_pool.dtype),
-                (0, slot, 0, 0, 0))
+            with jax.named_scope("reproject"):
+                kt = jnp.einsum("lhmd,lhdr->lmhr", kk,
+                                evecs_l[..., :r_keep])
+                kt_pool = jax.lax.dynamic_update_slice(
+                    kt_pool, kt[:, None].astype(kt_pool.dtype),
+                    (0, slot, 0, 0, 0))
         return ranks, basis, spectra, kt_pool, vetoed
 
     return decide

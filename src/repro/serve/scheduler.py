@@ -168,9 +168,9 @@ class Scheduler:
 
     def depth(self) -> int:
         """Requests in the system: queued + admitted (live slots). The
-        router's load signal; the engine also samples ``len(pending)``
-        and :meth:`n_live` into the ``queue.depth`` / ``slots.live``
-        observability gauges at admission time (repro.obs)."""
+        router's load signal. The engine sets the ``queue.depth`` and
+        ``slots.live`` observability gauges (repro.obs) from
+        ``len(pending)`` and the live slots at the end of every step."""
         return len(self.pending) + self.n_live()
 
     # -- eviction --------------------------------------------------------

@@ -20,6 +20,7 @@ Covers the layer from primitives up through the serving stack:
 """
 import json
 import threading
+import time
 
 import jax
 import numpy as np
@@ -28,9 +29,9 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import RankConfig
 from repro.models.api import get_model
-from repro.obs import (FlightRecorder, Gauge, Histogram, MetricsRegistry,
-                       SpanTracer, StatsView, Stopwatch, aggregate,
-                       aggregate_registry, validate_chrome_trace)
+from repro.obs import (PHASES, FlightRecorder, Gauge, Histogram,
+                       MetricsRegistry, SpanTracer, StatsView, Stopwatch,
+                       aggregate, aggregate_registry, validate_chrome_trace)
 from repro.serve import (Engine, EngineConfig, EngineStopped, FrontEnd,
                          SamplingParams)
 
@@ -165,7 +166,6 @@ def test_tracer_emits_valid_round_trippable_trace():
     tr.async_begin("request", 7, args={"rid": 7})
     tr.instant("first_token", tid=1, cat="request")
     tr.complete("dispatch", tr.now_us(), 12.5, tid=1000, cat="phase")
-    tr.counter("queue", {"depth": 2.0})
     tr.async_end("request", 7, args={"reason": "eos"})
     doc = tr.chrome_trace(metadata={"engine_id": 3})
     assert validate_chrome_trace(doc) == []
@@ -178,6 +178,18 @@ def test_tracer_emits_valid_round_trippable_trace():
     assert len(small.events) == 2 and small.dropped == 3
     small.clear()
     assert small.events == [] and small.dropped == 0
+
+
+def test_tracer_origin_maps_event_times_to_perf_counter():
+    tr = SpanTracer()
+    assert tr._t0 == tr.origin_s
+    t0 = time.perf_counter()
+    tr.instant("x")
+    t1 = time.perf_counter()
+    at = tr.origin_s + tr.events[-1]["ts"] * 1e-6
+    assert t0 - 1e-9 <= at <= t1 + 1e-9
+    tr.clear()
+    assert tr._t0 == tr.origin_s >= t1
 
 
 def test_trace_validator_rejects_malformed():
@@ -297,6 +309,47 @@ def test_obs_on_off_token_parity(setup, factor, sampling, tmp_path):
     assert tel["veto_fires"] >= 0 and tel["per_layer_uniform"]
     grid = set(cfg.rank.rank_grid) | {-1}
     assert all(v in grid for row in tel["kept_rank"] for v in row)
+
+
+@pytest.mark.parametrize("obs_trace", [False, True],
+                         ids=["tracing-off", "tracing-on"])
+def test_phase_seconds_are_always_counted(setup, obs_trace):
+    """The step phases' seconds accumulate with span tracing off too; with
+    it on, each phase's counter is the sum of its X events."""
+    cfg, params = setup
+    eng, _ = _run(cfg, params, [SamplingParams(max_new=6)] * 3,
+                  _prompts(3, seed=4), obs_trace=obs_trace, sampling=False,
+                  factor=True)
+    m = eng.obs.snapshot()["metrics"]
+    secs = {p: m[f"serve.phase.{p}_s"] for p in PHASES}
+    assert secs == {p: eng.stats[f"phase.{p}_s"] for p in PHASES}
+    assert secs["dispatch"] > 0 and secs["deliver"] > 0
+    evs = eng.obs.chrome_trace()["traceEvents"]
+    for p in PHASES:
+        traced = sum(e["dur"] for e in evs
+                     if e.get("cat") == "phase" and e["name"] == p) * 1e-6
+        assert traced == (pytest.approx(secs[p], rel=1e-9, abs=1e-12)
+                          if obs_trace else 0.0)
+
+
+def test_step_gauges_follow_the_slots(setup):
+    cfg, params = setup
+    eng = Engine(cfg, params, config=EngineConfig(
+        n_slots=2, max_len=48, page_size=8, segment_len=8, max_new_cap=8,
+        prefill_chunk=8))
+    for p in _prompts(3, seed=5):
+        eng.submit(p, SamplingParams(max_new=4))
+    m = eng.obs.registry
+    for _ in range(3):
+        eng.step()
+        live = [st for st in eng.core.sched.slots if st.active]
+        assert m.get("slots.live").value == len(live) == 2
+        assert m.get("queue.depth").value == 1
+        assert m.get("kv.positions_live").value == sum(
+            st.prefilled + st.n_out for st in live) > 0
+    eng.run()
+    assert [m.get(g).value for g in ("slots.live", "queue.depth",
+                                     "kv.positions_live")] == [0, 0, 0]
 
 
 def test_frontend_step_exception_dumps_flight_ring(setup, tmp_path):
