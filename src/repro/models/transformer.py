@@ -9,6 +9,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import nn
 from repro.configs.base import ModelConfig
@@ -304,7 +305,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     """One fused decode step over every serving slot of a slot-paged cache
     (repro.serve): heterogeneous streams share ONE executable.
 
-    pool_k/pool_v: (L, P, page_size, hkv, dh) shared page pools;
+    pool_k/pool_v: (L, P, page_size, hkv * dh) shared page pools, pages
+    major and each position's heads flattened into one row (see
+    repro.serve.kv_cache);
     page_table: (n_slots, pages_per_slot) physical page ids (page 0 is the
     scratch page); tokens: (n_slots, C) int32 (C = 1 for pure decode);
     slot_lens: (n_slots,) valid prefix length per slot BEFORE this step;
@@ -371,6 +374,14 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     weighted-Gram state feeding the next segment decision (Eq. 9 veto
     must see accepted tokens only). Causality makes the deferred sum of
     accepted queries bitwise equal to the sequential one-token updates.
+
+    **The pools stay in place.** The layer scan carries the stacked
+    ``k``/``v`` (and ``kt``/``mass``) pools instead of slicing them as its
+    xs and restacking them as its ys: each layer scatters its new tokens
+    at ``[layer, page, offset]`` and gathers its pages with one
+    ``pool[layer, page_table]``, both on the whole stack, so a step whose
+    caller donates the pools touches only the positions it writes and the
+    pages it reads, whatever the pool's size.
 
     Returns (logits (n_slots, 1, V), pools) with pools a dict holding the
     updated ``k``/``v`` pools plus ``kt``/``mass`` when those were given.
@@ -442,8 +453,12 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                   < jnp.minimum(slot_ranks, r_keep)[:, None]
                   ).astype(jnp.float32)             # (ns, r_keep)
 
-    def body(x, xs):
-        lp, kp, vp, basis_l, extra = xs
+    def body(carry, xs):
+        # ``li`` is a traced scalar under lax.scan and a static numpy int
+        # in the unrolled form; either way every pool access below
+        # indexes the carried stack, never a per-layer copy
+        x, kp, vp, extra = carry
+        lp, li, basis_l = xs
         ktp, mp = extra.get("kt"), extra.get("mass")
         p = lp["attn"]
         with jax.named_scope("qkv"):
@@ -458,26 +473,28 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
         with jax.named_scope("kv_write"):
-            kp = kp.at[phys, off].set(k.astype(kp.dtype))
-            vp = vp.at[phys, off].set(v.astype(vp.dtype))
+            kp = kp.at[li, phys, off].set(
+                k.reshape(ns, C, hkv * dh).astype(kp.dtype))
+            vp = vp.at[li, phys, off].set(
+                v.reshape(ns, C, hkv * dh).astype(vp.dtype))
             if ktp is not None:
                 # factor-form cache: append the new tokens' factors under
                 # the slot's segment basis
                 kt_new = jnp.einsum("bshd,bhdr->bshr",
                                     k.astype(jnp.float32), basis_l)
-                ktp = ktp.at[slot_rows, slot_pos].set(
+                ktp = ktp.at[li, slot_rows, slot_pos].set(
                     kt_new.astype(ktp.dtype))
         rank_on = rcfg.mode != "off" and slot_ranks is not None
         with jax.named_scope("kv_read"):
-            vg = vp[page_table].reshape(ns, M, hkv, dh)
+            vg = vp[li, page_table].reshape(ns, M, hkv, dh)
             if not rank_on or ktp is None or mixed:
-                kg = kp[page_table].reshape(ns, M, hkv, dh)
+                kg = kp[li, page_table].reshape(ns, M, hkv, dh)
                 # stale page contents (freed + re-issued pages) must not
                 # leak: zero everything beyond the valid prefix
                 k_dense = kg * valid[:, :, None, None].astype(kg.dtype)
             if ktp is not None:
                 # the slot-indexed factors: r/d of the dense K bytes
-                ktg = ktp[:ns]                        # (ns, M, hkv, r)
+                ktg = ktp[li, :ns]                    # (ns, M, hkv, r)
                 k_fac = (ktg * valid[:, :, None, None].astype(ktg.dtype)
                          ).astype(x.dtype)
         if not rank_on:
@@ -553,8 +570,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                 w = (probs.astype(jnp.float32)
                      * write_ok[:, None, :, None]).sum(axis=2)   # (ns, hq, M)
                 w_tok = kv_group_mean(w, hkv)                    # (ns, hkv, M)
-                mp = (jnp.where(new_cell[:, :, None], 0.0, mp)
-                      + jnp.swapaxes(w_tok, 1, 2).astype(mp.dtype))
+                mp_l = (jnp.where(new_cell[:, :, None], 0.0, mp[li])
+                        + jnp.swapaxes(w_tok, 1, 2).astype(mp.dtype))
+                mp = jax.lax.dynamic_update_index_in_dim(mp, mp_l, li, 0)
             if mass_defer:
                 # per-query mass, NOT summed over the chunk: the caller masks
                 # to the accepted queries before applying (spec verify)
@@ -580,21 +598,24 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
             new_extra["kt"] = ktp
         if mp is not None:
             new_extra["mass"] = mp
-        if mass_q is not None:
-            new_extra["mass_q"] = mass_q
-        return x, (kp, vp, new_extra)
+        ys = {} if mass_q is None else {"mass_q": mass_q}
+        return (x, kp, vp, new_extra), ys
 
     from repro.models.common import scan_or_unroll
     with jax.named_scope("layers"):
+        L = cfg.num_layers
         basis_xs = (basis if basis is not None else
-                    jnp.zeros((cfg.num_layers, ns, hkv, dh, 1), jnp.float32))
-        extra_xs = {}
+                    jnp.zeros((L, ns, hkv, dh, 1), jnp.float32))
+        extra = {}
         if kt_pool is not None:
-            extra_xs["kt"] = kt_pool
+            extra["kt"] = kt_pool
         if mass_pool is not None:
-            extra_xs["mass"] = mass_pool
-        x, (nk, nv, n_extra) = scan_or_unroll(
-            body, x, (params["layers"], pool_k, pool_v, basis_xs, extra_xs),
+            extra["mass"] = mass_pool
+        # a host array of layer ids: scanned it becomes the traced index,
+        # unrolled each layer reads a static one
+        (x, nk, nv, n_extra), ys = scan_or_unroll(
+            body, (x, pool_k, pool_v, extra),
+            (params["layers"], np.arange(L, dtype=np.int32), basis_xs),
             unroll=not cfg.scan_layers)
     with jax.named_scope("head"):
         if C > 1 and not return_all_logits:
@@ -607,5 +628,5 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                   if head is not None else
                   jnp.einsum("bsd,vd->bsv", x,
                              params["embed"].astype(x.dtype)))
-    pools = {"k": nk, "v": nv, **n_extra}
+    pools = {"k": nk, "v": nv, **n_extra, **ys}
     return logits, pools

@@ -1038,8 +1038,9 @@ class ServeEngine:
         ns, ps = self.n_slots, self.cache.page_size
         pos = np.maximum(self.cache.lens - 1, 0)
         phys = self.cache.page_table[np.arange(ns), pos // ps]
-        k_tok = self.cache.k_pool[0][jnp.asarray(phys),
-                                     jnp.asarray(pos % ps)]
+        k_tok = self.cache.k_pool[0, jnp.asarray(phys),
+                                  jnp.asarray(pos % ps)].reshape(
+            ns, self.cfg.num_kv_heads, -1)
         drift = np.asarray(  # inv-ok[R1]: drift check runs on the decide cadence (every decide_every steps), one small-vector fetch, never per decode step
             self._drift(k_tok, self.cache.basis[0], self.cache.ranks))
         for i in live:

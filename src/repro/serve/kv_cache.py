@@ -2,10 +2,20 @@
 
 Layout: one shared physical page pool per layer stack,
 
-    k_pool, v_pool: (L, n_pages, page_size, hkv, dh)
+    k_pool, v_pool: (L, n_pages, page_size, hkv * dh)
 
-plus a per-slot page table ``(n_slots, pages_per_slot)`` of physical page
-ids. A *slot* is a decode lane in the fused step executable; a slot's
+pages major, each position's kv heads flattened into one row. The minor
+dims ``(page_size, hkv * dh)`` then tile a TPU's (8, 128) float32 layout
+without padding whenever ``hkv * dh`` is a multiple of 128, so the
+backend keeps the stack page-major and the fused step's per-layer page
+scatter and page gather work on it in place (a ``(.., hkv, dh)`` minor
+pair such as (12, 64) would pad 2.7x, and the backend would lay the pool
+out pages-minor and re-lay a whole layer per access). Every interface
+still speaks ``(.., hkv, dh)``: ``write_prefill`` takes it and the views
+return it, reshaping inside.
+
+There is also a per-slot page table ``(n_slots, pages_per_slot)`` of
+physical page ids. A *slot* is a decode lane in the fused step executable; a slot's
 logical sequence dim is the concatenation of its pages, so admission only
 needs ``ceil(need / page_size)`` free pages anywhere in the pool — no
 contiguous-region allocation, no per-request max_len reservation in one
@@ -98,8 +108,10 @@ class PagedKVCache:
         dtype = nn.dt(cfg.dtype)
         dh = cfg.resolved_head_dim()
         L, hkv = cfg.num_layers, cfg.num_kv_heads
-        self.k_pool = jnp.zeros((L, self.n_pages, page_size, hkv, dh), dtype)
-        self.v_pool = jnp.zeros((L, self.n_pages, page_size, hkv, dh), dtype)
+        self.k_pool = jnp.zeros((L, self.n_pages, page_size, hkv * dh),
+                                dtype)
+        self.v_pool = jnp.zeros((L, self.n_pages, page_size, hkv * dh),
+                                dtype)
         self.page_table = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self._free: List[int] = list(range(self.n_pages - 1, 0, -1))  # not 0
         self.ref = np.zeros((self.n_pages,), np.int32)
@@ -260,14 +272,14 @@ class PagedKVCache:
         when ``mass_layers`` (L, s, hkv) is given, re-seeded with the
         prompt's per-key causal attention mass. Control-plane op (one
         dispatch per admission)."""
-        s = k_layers.shape[1]
+        L, s = k_layers.shape[:2]
         pos = np.arange(s)
         phys = jnp.asarray(self.page_table[slot][pos // self.page_size])
         off = jnp.asarray(pos % self.page_size)
         self.k_pool = self.k_pool.at[:, phys, off].set(
-            k_layers.astype(self.k_pool.dtype))
+            k_layers.reshape(L, s, -1).astype(self.k_pool.dtype))
         self.v_pool = self.v_pool.at[:, phys, off].set(
-            v_layers.astype(self.v_pool.dtype))
+            v_layers.reshape(L, s, -1).astype(self.v_pool.dtype))
         if self.mass_pool is not None:
             mp = self.mass_pool.at[:, slot].set(0.0)
             if mass_layers is not None:
@@ -282,20 +294,21 @@ class PagedKVCache:
         """(L, max_len, hkv, dh) contiguous K/V view of one slot (testing /
         debugging; the fused step gathers all slots in-graph)."""
         pt = jnp.asarray(self.page_table[slot])
+        hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim()
         def view(pool):
-            g = pool[:, pt]                           # (L, pages, ps, hkv, dh)
-            return g.reshape(g.shape[0], -1, *g.shape[3:])
+            g = pool[:, pt]                      # (L, pages, ps, hkv * dh)
+            return g.reshape(g.shape[0], -1, hkv, dh)
         return view(self.k_pool), view(self.v_pool)
 
 
 def gather_views(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-                 page_table: jnp.ndarray):
+                 page_table: jnp.ndarray, hkv: int):
     """In-graph gather of every slot's logical K/V view.
 
-    k_pool/v_pool: (L, P, ps, hkv, dh); page_table: (n_slots, pages).
+    k_pool/v_pool: (L, P, ps, hkv * dh); page_table: (n_slots, pages).
     Returns (L, n_slots, M, hkv, dh) x2 with M = pages * ps."""
     def view(pool):
-        g = pool[:, page_table]              # (L, n_slots, pages, ps, hkv, dh)
+        g = pool[:, page_table]            # (L, n_slots, pages, ps, hkv * dh)
         L, ns = g.shape[0], g.shape[1]
-        return g.reshape(L, ns, -1, *g.shape[4:])
+        return g.reshape(L, ns, -1, hkv, g.shape[-1] // hkv)
     return view(k_pool), view(v_pool)

@@ -112,9 +112,9 @@ def make_decide_fn(cfg: ModelConfig, policy_params=None) -> Callable:
         # veto is disabled): fall back to the fresh-slot default r_max
         prev_rank = jnp.where(has_rank, prev_rank, jnp.int32(g_hi))
         with jax.named_scope("gather_k"):
-            gathered = k_pool[:, pt_row]       # (L, pages, ps, h, d)
+            gathered = k_pool[:, pt_row]       # (L, pages, ps, hkv * dh)
             L = gathered.shape[0]
-            kv = gathered.reshape(L, -1, *gathered.shape[3:])
+            kv = gathered.reshape(L, -1, cfg.num_kv_heads, dh)
             M = kv.shape[1]
             valid = (jnp.arange(M) < kv_len).astype(jnp.float32)
             kk = jnp.swapaxes(kv, 1, 2).astype(jnp.float32) \

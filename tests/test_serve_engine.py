@@ -124,7 +124,7 @@ def test_paged_cache_roundtrip_and_release():
         cache.write_prefill(slot, jnp.asarray(k), jnp.asarray(v))
         written[slot] = (k, v, s)
     kv_all, vv_all = gather_views(cache.k_pool, cache.v_pool,
-                                  jnp.asarray(cache.page_table))
+                                  jnp.asarray(cache.page_table), hkv)
     for slot, (k, v, s) in written.items():
         kg, vg = cache.gather_slot(slot)
         np.testing.assert_array_equal(np.asarray(kg[:, :s]), k)
@@ -137,6 +137,75 @@ def test_paged_cache_roundtrip_and_release():
     cache.release(1)
     assert cache.free_pages == free0 + cache.pages_needed(24)
     assert cache.allocate(1, 16)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_mixed_step_writes_pools_in_place(scan_layers):
+    """One mixed step (a decode row, a prefill row, a dead lane) over pools
+    full of random values: the step writes each new token's K/V at its
+    (layer, page, offset) and leaves every other entry bitwise as it was
+    (page 0 is the scratch page that absorbs dead-lane writes)."""
+    cfg = _cfg("off").with_(scan_layers=scan_layers)
+    fns = get_model(cfg)
+    params = fns.init(RNG)
+    L, hkv, dh = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim()
+    C, s0, ps = 4, 9, 8
+    cache = PagedKVCache(cfg, n_slots=3, max_len=24, page_size=ps)
+    rnd = np.random.default_rng(1)
+    cache.k_pool = jnp.asarray(
+        rnd.normal(size=cache.k_pool.shape).astype(np.float32))
+    cache.v_pool = jnp.asarray(
+        rnd.normal(size=cache.v_pool.shape).astype(np.float32))
+    toks = jax.random.randint(jax.random.PRNGKey(7), (2, 16), 0,
+                              cfg.vocab_size)
+    # slot 0 decodes after s0 prompt tokens; slot 1 prefills its first
+    # chunk; slot 2 is a dead lane
+    mono0 = fns.init_cache(1, 24)
+    _, mono0 = fns.decode_step(params, mono0, toks[0:1, :s0])
+    assert cache.allocate(0, s0 + 1) and cache.allocate(1, C)
+    cache.write_prefill(0, mono0["k"][:, 0, :s0], mono0["v"][:, 0, :s0])
+    kg, vg = cache.gather_slot(0)
+    kv_all, vv_all = gather_views(cache.k_pool, cache.v_pool,
+                                  jnp.asarray(cache.page_table), hkv)
+    for got in (kg, kv_all[:, 0]):
+        np.testing.assert_array_equal(np.asarray(got[:, :s0]),
+                                      np.asarray(mono0["k"][:, 0, :s0]))
+    for got in (vg, vv_all[:, 0]):
+        np.testing.assert_array_equal(np.asarray(got[:, :s0]),
+                                      np.asarray(mono0["v"][:, 0, :s0]))
+    k_in, v_in = np.asarray(cache.k_pool), np.asarray(cache.v_pool)
+    tokens = jnp.zeros((3, C), jnp.int32)
+    tokens = tokens.at[0, 0].set(toks[0, s0]).at[1].set(toks[1, :C])
+    _, pools = jax.jit(fns.decode_step_paged)(
+        params, cache.k_pool, cache.v_pool, jnp.asarray(cache.page_table),
+        tokens, slot_lens=jnp.asarray([s0, 0, 0], jnp.int32),
+        active=jnp.asarray([True, True, False]),
+        q_lens=jnp.asarray([1, C, 1], jnp.int32),
+        prefill_rows=jnp.asarray([False, True, False]))
+    k_out, v_out = np.asarray(pools["k"]), np.asarray(pools["v"])
+    assert k_out.shape == k_in.shape == (L, cache.n_pages, ps, hkv * dh)
+    # the step's own K/V of the new tokens, from the monolithic decode
+    _, mono0 = fns.decode_step(params, mono0, toks[0:1, s0:s0 + 1])
+    _, mono1 = fns.decode_step(params, fns.init_cache(1, 24),
+                               toks[1:2, :C])
+    pt = cache.page_table
+    coords = [(pt[0, s0 // ps], s0 % ps)] + [(pt[1, j // ps], j % ps)
+                                             for j in range(C)]
+    want_k = [mono0["k"][:, 0, s0]] + [mono1["k"][:, 0, j] for j in range(C)]
+    want_v = [mono0["v"][:, 0, s0]] + [mono1["v"][:, 0, j] for j in range(C)]
+    written = np.zeros((cache.n_pages, ps), bool)
+    for (page, off), wk, wv in zip(coords, want_k, want_v):
+        written[page, off] = True
+        np.testing.assert_allclose(k_out[:, page, off],
+                                   np.asarray(wk).reshape(L, -1),
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(v_out[:, page, off],
+                                   np.asarray(wv).reshape(L, -1),
+                                   atol=1e-5, rtol=1e-5)
+    kept = ~written
+    kept[0] = False                                  # the scratch page
+    np.testing.assert_array_equal(k_out[:, kept], k_in[:, kept])
+    np.testing.assert_array_equal(v_out[:, kept], v_in[:, kept])
 
 
 # ---------------------------------------------------------------------------
@@ -262,7 +331,8 @@ def test_fullrank_basis_projection_matches_off():
         cache.allocate(slot, s + 1)
         cache.write_prefill(slot, mono["k"][:, 0, :s], mono["v"][:, 0, :s])
     kv_all, _ = gather_views(cache.k_pool, cache.v_pool,
-                             jnp.asarray(cache.page_table))
+                             jnp.asarray(cache.page_table),
+                             cfg.num_kv_heads)
     lens = jnp.asarray(cache.lens, jnp.int32)
     valid = jnp.arange(kv_all.shape[2])[None, :] < lens[:, None]
     kk = (jnp.swapaxes(kv_all, 2, 3)
