@@ -10,20 +10,23 @@ sanitizer's ``observability`` scenario runs the steady loop with
 tracing enabled under ``jax.transfer_guard("disallow")`` and a compile
 counter to pin exactly that).
 
-Rank telemetry is the one place device values are involved, and it is
-**export-time only**: :meth:`Observability.rank_telemetry` derives the
-kept-rank series, switch counts and factor-read bytes/token from the
-engine's ``rank_history`` (device arrays the loop already keeps,
-appended without synchronisation) and fetches the per-decision Eq. 9
-veto flags — device booleans the jitted ``decide`` call returns and the
-engine banks unfetched — in one batched ``device_get`` when a report is
-actually requested. The fused loop never gains a host sync (invariant
-R1) no matter which observability features are enabled.
+Rank values are the one place device values are involved, and they are
+fetched **at read time only**. The jitted ``decide`` returns each
+decision's chosen rank and Eq. 9 veto flag on the device; the engine
+banks them unfetched and adds them into the counters
+``serve.rank.chosen_sum`` and ``serve.rank.vetoes`` (its stats
+``rank_chosen_sum`` / ``rank_vetoes``) in one batched ``device_get``
+when those are read, through :meth:`snapshot`, :meth:`prometheus` or the
+stats view. :meth:`Observability.rank_telemetry` derives the kept-rank
+series, switch counts and factor-read bytes/token from the engine's
+``rank_history`` (device arrays the loop already keeps, appended
+without synchronisation). The fused loop never gains a host sync
+(invariant R1) no matter which observability features are enabled.
 """
 from __future__ import annotations
 
 import itertools
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import (DEFAULT_COUNT_BUCKETS, MetricsRegistry,
@@ -33,6 +36,10 @@ from repro.obs.tracing import PHASES, SpanTracer, StepPhases, Stopwatch
 __all__ = ["Observability", "Stopwatch"]
 
 _ENGINE_SEQ = itertools.count()
+
+# the engine's rank stats -> the registry counters that back them
+RANK_STATS = {"rank_chosen_sum": "serve.rank.chosen_sum",
+              "rank_vetoes": "serve.rank.vetoes"}
 
 
 class Observability:
@@ -72,7 +79,11 @@ class Observability:
         self._c_decisions = r.counter("rank.decisions")
         self._c_forced = r.counter("rank.forced_decides")
         self._c_drift = r.counter("rank.drift_triggers")
-        self._c_veto = r.counter("rank.veto_fires")
+        # filled from the engine's banked decisions when read (see the
+        # module docstring); in the engine's stats under RANK_STATS' keys
+        self._c_rank_chosen = r.counter("serve.rank.chosen_sum")
+        self._c_rank_vetoes = r.counter("serve.rank.vetoes")
+        self._refresh = None
         self._g_queue = r.gauge("queue.depth")
         self._g_live = r.gauge("slots.live")
         self._g_kv = r.gauge("kv.positions_live")
@@ -81,13 +92,27 @@ class Observability:
 
     # -- engine wiring ----------------------------------------------------
 
-    def stats_view(self, init: Dict[str, Any],
-                   gauges=("eff_draft_k",)) -> StatsView:
+    def stats_view(self, init: Dict[str, Any], gauges=("eff_draft_k",),
+                   refresh: Optional[Callable[[], None]] = None
+                   ) -> StatsView:
         """The engine's legacy ``stats`` surface as a registry view (and
         the reset path: re-binding zeroes the backing metrics), with the
-        step-phase seconds ``phase.<p>_s`` added."""
-        init = {**init, **{f"phase.{p}_s": 0.0 for p in PHASES}}
-        return StatsView(self.registry, init, prefix="serve", gauges=gauges)
+        step-phase seconds ``phase.<p>_s`` and the rank counters
+        ``rank_chosen_sum`` / ``rank_vetoes`` added. ``refresh`` adds
+        what the engine has banked into the rank counters
+        (:meth:`on_ranks_fetched`); it runs before they are read, here
+        or by the exporters."""
+        init = {**init, **{f"phase.{p}_s": 0.0 for p in PHASES},
+                **{k: 0 for k in RANK_STATS}}
+        self._refresh = refresh
+        return StatsView(self.registry, init, prefix="serve", gauges=gauges,
+                         names=RANK_STATS, refresh=refresh, lazy=RANK_STATS)
+
+    def on_ranks_fetched(self, chosen_sum: int, vetoes: int) -> None:
+        """Banked decisions, fetched: the ranks they chose, summed, and
+        how many the Eq. 9 bound vetoed."""
+        self._c_rank_chosen.inc(chosen_sum)
+        self._c_rank_vetoes.inc(vetoes)
 
     def reset_run(self) -> None:
         """Engine reset: clear the per-run trace buffer (the flight ring
@@ -188,6 +213,8 @@ class Observability:
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-ready point-in-time export of this engine's shard."""
+        if self._refresh is not None:
+            self._refresh()
         return {
             "engine_id": self.engine_id,
             "metrics": self.registry.snapshot(),
@@ -204,6 +231,8 @@ class Observability:
         }
 
     def prometheus(self, namespace: str = "repro") -> str:
+        if self._refresh is not None:
+            self._refresh()
         return self.registry.prometheus_text(namespace)
 
     def chrome_trace(self) -> Dict[str, Any]:
@@ -227,9 +256,8 @@ class Observability:
         kept-rank time series, switch counts, Eq. 9 veto fires and
         factor-read bytes/token, derived from device state the loop
         already banked (``rank_history`` and the unfetched per-decision
-        veto flags). The only host transfers happen HERE, at read time —
-        never inside the fused loop."""
-        import jax
+        values behind ``stats['rank_vetoes']``). The only host transfers
+        happen HERE, at read time — never inside the fused loop."""
         import numpy as np
 
         series = engine.ranks_per_step()          # host: -1 = off/dead
@@ -243,12 +271,7 @@ class Observability:
                 col = mat[live[:, j], j]
                 if col.size > 1:
                     switches += int((np.diff(col) != 0).sum())
-        pend = getattr(engine, "_veto_pending", ())
-        veto = 0
-        if len(pend):
-            flags = jax.device_get(list(pend))
-            veto = int(sum(bool(f) for f in flags))
-        self._c_veto.set(veto)
+        veto = int(engine.stats["rank_vetoes"])   # fetches what is banked
         # analytic factor-read bytes/token for currently-live slots
         # (same formula as repro.serve.traces: L * kv_len * hkv * r * 4)
         cfg = engine.cfg

@@ -35,7 +35,7 @@ import re
 import threading
 from bisect import bisect_left
 from collections.abc import MutableMapping
-from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple, Union
+from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple, Union
 
 Number = Union[int, float]
 
@@ -292,22 +292,37 @@ class StatsView(MutableMapping):
     stat names); reads and ``stats[k] = v`` / ``stats[k] += v`` writes
     go straight to the backing Counter/Gauge. Re-binding the same keys
     on an existing registry (engine reset) re-zeroes them to their init
-    values — exactly the semantics of rebuilding the old dict."""
+    values — exactly the semantics of rebuilding the old dict.
 
-    __slots__ = ("_metrics",)
+    Keys in ``names`` are backed by the metric of that name instead of
+    ``<prefix>.<key>``. Keys in ``lazy`` hold values the owner banks
+    elsewhere: ``refresh()`` runs before such a key is read and before
+    the view is iterated (``dict(view)``), never on other reads or on
+    writes."""
+
+    __slots__ = ("_metrics", "_refresh", "_lazy")
 
     def __init__(self, registry: MetricsRegistry, init: Mapping[str, Number],
-                 *, prefix: str = "serve", gauges: Sequence[str] = ()):
+                 *, prefix: str = "serve", gauges: Sequence[str] = (),
+                 names: Optional[Mapping[str, str]] = None,
+                 refresh: Optional[Callable[[], None]] = None,
+                 lazy: Sequence[str] = ()):
         metrics: Dict[str, Counter] = {}
         for k, v in init.items():
             cls = Gauge if k in gauges else Counter
-            m = registry._get_or_make(cls, f"{prefix}.{k}", init=v)
+            m = registry._get_or_make(
+                cls, (names or {}).get(k, f"{prefix}.{k}"), init=v)
             m.init = v
             m.value = v
             metrics[k] = m
         object.__setattr__(self, "_metrics", metrics)
+        object.__setattr__(self, "_refresh", refresh)
+        object.__setattr__(self, "_lazy", frozenset(lazy) if refresh
+                           else frozenset())
 
     def __getitem__(self, k: str) -> Number:
+        if k in self._lazy:
+            self._refresh()
         return self._metrics[k].value
 
     def __setitem__(self, k: str, v: Number) -> None:
@@ -317,6 +332,8 @@ class StatsView(MutableMapping):
         raise TypeError("StatsView keys are fixed at construction")
 
     def __iter__(self) -> Iterator[str]:
+        if self._lazy:
+            self._refresh()
         return iter(self._metrics)
 
     def __len__(self) -> int:

@@ -197,6 +197,9 @@ class ServeEngine:
         # submit() and admission (scheduler pop + device staging) may run
         # on different threads; one lock covers both critical sections
         self._lock = threading.Lock()
+        # guards the decisions banked for the rank counters (appended by
+        # the stepping thread, drained by whichever thread reads them)
+        self._rank_lock = threading.Lock()
         self._buckets = tuple(buckets) if buckets else prefill_buckets(max_len)
         self.sched = Scheduler(n_slots, self._buckets)
         self.fns = get_model(cfg)
@@ -274,21 +277,25 @@ class ServeEngine:
         # but the registry is the single accumulation point and the
         # exporters see these values for free. Re-binding zeroes the
         # backing metrics — the old "fresh dict per reset" semantics.
-        self.stats = self.obs.stats_view(
-            {"compile_s": 0.0, "prefill_s": 0.0, "decode_s": 0.0,
-             "steps": 0, "tokens_decoded": 0, "prefills": 0,
-             "decides": 0, "mixed_steps": 0, "stall_s": 0.0,
-             "prefill_tokens": 0, "prefix_hits": 0,
-             "prefix_misses": 0, "prefix_reused_tokens": 0,
-             "prefix_cow": 0, "prefix_evictions": 0,
-             "spec_steps": 0, "spec_drafted": 0,
-             "spec_accepted": 0, "spec_tokens": 0,
-             "eff_draft_k": self.draft_k if self.speculative else 0})
+        # Under the rank lock, so that a drain running on another thread
+        # lands wholly before the re-zeroing or finds nothing banked.
+        with self._rank_lock:
+            self.stats = self.obs.stats_view(
+                {"compile_s": 0.0, "prefill_s": 0.0, "decode_s": 0.0,
+                 "steps": 0, "tokens_decoded": 0, "prefills": 0,
+                 "decides": 0, "mixed_steps": 0, "stall_s": 0.0,
+                 "prefill_tokens": 0, "prefix_hits": 0,
+                 "prefix_misses": 0, "prefix_reused_tokens": 0,
+                 "prefix_cow": 0, "prefix_evictions": 0,
+                 "spec_steps": 0, "spec_drafted": 0,
+                 "spec_accepted": 0, "spec_tokens": 0,
+                 "eff_draft_k": self.draft_k if self.speculative else 0},
+                refresh=self._drain_rank_counters)
+            # per-decision (ranks, slot, vetoed) as UNFETCHED device values:
+            # _drain_rank_counters fetches them in one batch when the rank
+            # counters are read, so they cost the loop nothing (R1)
+            self._rank_pending: List[Tuple[jnp.ndarray, int, jnp.ndarray]] = []
         self.obs.reset_run()
-        # per-decision Eq. 9 veto flags, banked as UNFETCHED device bools
-        # — obs.rank_telemetry() fetches them in one batch at export
-        # time, so veto observability costs the loop nothing (R1)
-        self._veto_pending: List[jnp.ndarray] = []
         # adaptive-draft controller state (host-only; never traced)
         self._eff_k = self.draft_k if self.speculative else 0
         self._accept_ewma = 1.0
@@ -991,13 +998,13 @@ class ServeEngine:
                 self._pt_dev, self._lens_dev, self.cache.ranks,
                 self.cache.basis, self.cache.spectra, np.int32(i),
                 np.bool_(self.has_rank[i]), np.int32(st.t))
-            # the Eq. 9 veto flag is a device bool: bank it UNFETCHED —
-            # obs.rank_telemetry() reads the whole batch in one
-            # device_get at export time, so veto telemetry adds no sync
-            # to the loop
-            self._veto_pending.append(vetoed)
+            # the chosen rank and the Eq. 9 veto flag stay on the
+            # device: bank them UNFETCHED for the rank counters, which
+            # read the whole batch in one device_get (no sync here)
+            with self._rank_lock:
+                self._rank_pending.append((self.cache.ranks, int(i), vetoed))
+                self.stats["decides"] += 1
             st.t += 1
-            self.stats["decides"] += 1
             self.obs.on_decide(int(i), st.t - 1, forced=forced)
             if self.trace is not None:
                 s2_h, rank_h = jax.device_get(  # inv-ok[R1]: trace recording fetches the decision's spectra/rank once per segment boundary (the decide cadence), never per decode step
@@ -1016,6 +1023,22 @@ class ServeEngine:
                     node.snap_spectra = self.cache.spectra[i]
         self.has_rank |= boundary
         self.force_decide &= ~boundary
+
+    def _drain_rank_counters(self) -> None:
+        """Add the banked decisions into ``stats['rank_chosen_sum']``
+        (the rank each chose) and ``stats['rank_vetoes']`` (Eq. 9 veto
+        fires). Called by the stats view when either is read, never by
+        the step loop: one batched fetch of values ``decide`` already
+        returned. Holding the lock that the decision path takes to bank
+        a decision and count it in ``decides`` keeps the three in step."""
+        with self._rank_lock:
+            pend, self._rank_pending = self._rank_pending, []
+            if not pend:
+                return
+            got = jax.device_get([(r, v) for r, _, v in pend])
+            self.obs.on_ranks_fetched(
+                sum(int(r[s]) for (r, _), (_, s, _) in zip(got, pend)),
+                sum(bool(v) for _, v in got))
 
     def _check_drift(self, live: List[int]) -> None:
         """Early re-decision trigger: measure the newest K token's residual

@@ -107,6 +107,24 @@ def test_statsview_dict_shim():
     assert r.get("serve.steps").value == 0
 
 
+def test_statsview_lazy_keys_refresh_only_when_read():
+    r = MetricsRegistry()
+    calls = []
+
+    def refresh():
+        calls.append(1)
+        r.get("serve.rank.vetoes").inc(2)
+
+    sv = StatsView(r, {"decides": 0, "rank_vetoes": 0},
+                   names={"rank_vetoes": "serve.rank.vetoes"},
+                   refresh=refresh, lazy=("rank_vetoes",))
+    sv["decides"] += 5                     # other keys never refresh
+    assert sv["decides"] == 5 and calls == []
+    assert sv["rank_vetoes"] == 2 and len(calls) == 1
+    assert dict(sv) == {"decides": 5, "rank_vetoes": 6}
+    assert r.get("serve.rank.vetoes").value == 6
+
+
 def test_aggregate_fleet_rollup():
     regs = []
     for n in (2, 5):
@@ -350,6 +368,87 @@ def test_step_gauges_follow_the_slots(setup):
     eng.run()
     assert [m.get(g).value for g in ("slots.live", "queue.depth",
                                      "kv.positions_live")] == [0, 0, 0]
+
+
+def _kept_rank_sum(series, seg):
+    """Sum of the ranks chosen at segment decisions, from the kept-rank
+    series alone: a request's run of live steps on a slot decides at
+    its decode steps 0, seg, 2 seg, ..."""
+    total = 0
+    for col in np.asarray(series).T:
+        live = np.append(col >= 0, False)
+        starts = np.flatnonzero(live & ~np.insert(live[:-1], 0, False))
+        for a in starts:
+            b = a + np.argmin(live[a:])
+            total += int(col[a:b:seg].sum())
+    return total
+
+
+def test_rank_counters_match_the_decisions(setup):
+    """``rank_chosen_sum`` and ``rank_vetoes`` count what each decision
+    returned, read through ``dict(stats)`` as a harness reads them; they
+    agree with ``rank_telemetry``; the loop never drains them, and a
+    steady run with them builds no new executable."""
+    from repro.analysis.sanitizer import CompileCounter, guard_steps
+    cfg, params = setup
+    # random draws keyed by (slot, clock): the slots' ranks differ, and
+    # a tight Eq. 9 threshold vetoes some switches
+    cfg = cfg.with_(rank=RankConfig(mode="random", rank_grid=(4, 8, 12),
+                                    segment_len=4, epsilon0=0.3))
+    eng = Engine(cfg, params, config=EngineConfig(
+        n_slots=2, max_len=64, page_size=8, segment_len=4, max_new_cap=24,
+        prefill_chunk=8))
+    core = eng.core
+    assert core.cache.factored
+    seen, drains = [], []
+    decide, drain = core._decide, core._drain_rank_counters
+
+    def recording(*a):
+        out = decide(*a)
+        seen.append((out[0], int(a[8]), out[4]))
+        return out
+
+    def counting():
+        drains.append(core.stats["steps"])
+        drain()
+
+    def serve(seed):
+        for p in _prompts(3, seed=seed, lo=10, hi=20):
+            eng.submit(p, SamplingParams(max_new=24))
+        eng.run()
+
+    core._decide, core._drain_rank_counters = recording, counting
+    eng.reset()                        # the stats view takes the wrapper
+    serve(seed=6)
+    assert drains == []                # the loop itself never drains
+    before = dict(eng.stats)
+    got = [(int(np.asarray(r)[s]), bool(v)) for r, s, v in seen]
+    assert before["decides"] == len(got) >= 12
+    assert before["rank_chosen_sum"] == sum(g for g, _ in got)
+    assert before["rank_vetoes"] == sum(v for _, v in got) >= 1
+    tel = eng.obs.rank_telemetry(core)
+    assert tel["veto_fires"] == before["rank_vetoes"]
+    assert _kept_rank_sum(tel["kept_rank"], 4) == before["rank_chosen_sum"]
+    m = eng.obs.snapshot()["metrics"]
+    assert m["serve.rank.chosen_sum"] == before["rank_chosen_sum"]
+    assert m["serve.rank.vetoes"] == before["rank_vetoes"]
+
+    # steady state: same shapes, fresh prompts, fused steps under a
+    # disallow transfer guard; reading the counters compiles nothing
+    eng.reset()
+    seen.clear()
+    drains.clear()
+    guard_steps(core)
+    counter = CompileCounter()
+    with counter.attached():
+        serve(seed=7)
+        assert drains == []
+        after = dict(eng.stats)
+    assert counter.count == 0, counter.messages
+    got = [(int(np.asarray(r)[s]), bool(v)) for r, s, v in seen]
+    assert after["decides"] == len(got) >= 12
+    assert after["rank_chosen_sum"] == sum(g for g, _ in got)
+    assert after["rank_vetoes"] == sum(v for _, v in got)
 
 
 def test_frontend_step_exception_dumps_flight_ring(setup, tmp_path):

@@ -7,6 +7,11 @@ kernel the serving engine dispatches, at the drrl-paper widths (12 q and
 step must keep its K/V pools in place: at the drrl-paper deployment no
 copy or slice of a whole pool, and little scratch memory.
 
+The engine's fused steps and its segment decision must fit one chip at
+the InternLM2-20B long-context deployment (4 of its 48 layers at
+published widths, bfloat16, 8 slots of 12288 positions, the factor pool
+on) with room to spare.
+
 Nothing runs: these catch what interpret mode cannot (tiling, fast-memory
 budget) at no chip time. The topology is described inside a fixture, so
 collection never loads the TPU library; without it the tests skip."""
@@ -18,9 +23,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.base import RankConfig
+from repro.core.drrl import init_agent
 from repro.kernels.decode_attn import flash_decode
 from repro.models.api import get_model
-from repro.serve import PagedKVCache
+from repro.serve import PagedKVCache, ServeEngine
 
 HQ = HKV = 12
 DH = 64
@@ -122,3 +129,74 @@ def test_mixed_step_keeps_pools_in_place_for_v5e(one_chip):
     assert not moved, f"whole-pool copies or slices: {moved[:8]}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.5e9, f"{temp / 1e9:.2f} GB of temporaries"
+
+
+# One v5e holds 16 GB. The longctx steps compile to 7.48 GB of
+# arguments (weights 5.4, K/V pools 1.6, factor pool 0.45) and 3.03 /
+# 3.18 GB of temporaries (pure decode / mixed), ``decide`` to 1.28 +
+# 0.43 GB. The limits leave the rest of the chip for the eager programs
+# and fail a change that brings back pool-sized temporaries (a K or V
+# pool is 0.8 GB; when the layer scan sliced and restacked the pools
+# each step, the steps took 5.8-5.9 GB of them).
+STEP_LIMIT = 12e9
+DECIDE_TEMP_LIMIT = 1e9
+
+
+def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
+    """The engine's two fused steps and ``decide`` at the longctx
+    deployment, lowered on shapes only, donating what the engine donates
+    on a chip. The engine is built at a tiny size: the executables take
+    every pool and weight as arguments, so only the widths come from it."""
+    grid = (16, 24, 32, 40, 48, 56, 64)
+    cfg = get_config("internlm2-20b").with_(
+        num_layers=4, max_seq_len=32768,
+        rank=RankConfig(mode="drrl", rank_grid=grid, segment_len=512))
+    agent = init_agent(jax.random.PRNGKey(1), cfg.rank, cfg.d_model)
+    eng = ServeEngine(cfg, None, agent, n_slots=1, max_len=16,
+                      max_new_cap=max_new, prefill_chunk=16)
+
+    def spec(x, dtype=None):
+        if isinstance(x, tuple):
+            x = jax.ShapeDtypeStruct(x, dtype)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        spec, jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0)))
+    c = jax.eval_shape(lambda: {
+        k: getattr(pc, k)
+        for pc in [PagedKVCache(cfg, n_slots, max_len)]
+        for k in ("k_pool", "v_pool", "kt_pool", "mass_pool", "basis",
+                  "spectra", "ranks")})
+    assert c["kt_pool"] is not None          # r_keep 64 < head_dim 128
+    c = {k: spec(v) for k, v in c.items()}
+    rows = spec((n_slots,), jnp.int32)
+    pt = spec((n_slots, max_len // 16), jnp.int32)
+    args = [params, c["k_pool"], c["v_pool"], c["kt_pool"], c["mass_pool"],
+            pt, spec((n_slots, 1), jnp.int32), rows, c["ranks"],
+            c["basis"], spec((n_slots,), bool),
+            spec((n_slots + 1, max_new), jnp.int32), rows,
+            spec((n_slots,), jnp.float32), rows,
+            spec((n_slots,), jnp.float32), spec((n_slots,), jnp.uint32)]
+    donate = (1, 2, 3, 4, 11)
+    out = {"step": jax.jit(eng._step_impl, donate_argnums=donate)
+           .lower(*args).compile(),
+           "mixed": jax.jit(eng._step_mixed_impl, donate_argnums=donate)
+           .lower(*args, spec((n_slots, max_len), jnp.int32)).compile()}
+    scalar = spec((), jnp.int32)
+    out["decide"] = jax.jit(eng._decide.__wrapped__,
+                            donate_argnums=(2, 6, 7)).lower(
+        c["k_pool"], c["mass_pool"], c["kt_pool"], pt, rows, c["ranks"],
+        c["basis"], c["spectra"], scalar, spec((), bool), scalar).compile()
+    return out
+
+
+def test_longctx_steps_and_decide_fit_one_v5e(one_chip):
+    progs = _longctx_programs(one_chip)
+    for name in ("step", "mixed"):
+        m = progs[name].memory_analysis()
+        total = m.argument_size_in_bytes + m.temp_size_in_bytes
+        assert total < STEP_LIMIT, (
+            f"{name}: {m.argument_size_in_bytes / 1e9:.2f} GB of arguments"
+            f" + {m.temp_size_in_bytes / 1e9:.2f} GB of temporaries")
+    temp = progs["decide"].memory_analysis().temp_size_in_bytes
+    assert temp < DECIDE_TEMP_LIMIT, f"decide: {temp / 1e9:.2f} GB"
