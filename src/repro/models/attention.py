@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, RankConfig
 from repro.core import lowrank as lr
 from repro.core import perturbation as pert
-from repro.models.common import apply_mrope, apply_rope, repeat_kv
+from repro.models.common import (apply_mrope, apply_rope, group_q_heads,
+                                 repeat_kv, ungroup_q_heads)
 
 
 # ---------------------------------------------------------------------------
@@ -35,29 +36,48 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
            score_spec=None, return_probs: bool = False) -> jnp.ndarray:
     """softmax(q k^T * scale) v.
 
-    ``return_probs`` also returns the probability tensor (b, h, sq, skv)
+    ``return_probs`` also returns the probability tensor (b, hq, sq, skv)
     (the serving engine's attention-mass feed); unsupported on the
     chunked path, which never materialises it.
 
-    q: (b, sq, h, dq)  k: (b, skv, h, dq)  v: (b, skv, h, dv).
+    q: (b, sq, hq, dq)  k: (b, skv, hkv, dq)  v: (b, skv, hkv, dv).
     ``dq`` may be a truncated rank r — the caller supplies the proper scale
     (always 1/sqrt(d_head_original), per the paper's Eq. 1).
     kv_len masks out cache positions >= kv_len. ``chunked`` streams over KV
     blocks with a running softmax (flash semantics in pure XLA).
+
+    GQA: ``hkv`` may divide ``hq``. The score and PV contractions run per
+    kv group over q viewed as (b, n_rep * sq, hkv, dq) rows
+    (:func:`group_q_heads`), so each kv head is read once instead of being
+    repeated ``n_rep`` times first; with ``hkv == hq`` that view is q
+    itself. The result, and the probs, equal those of
+    ``attend(q, repeat_kv(k), repeat_kv(v))`` with the same head order
+    (q head ``g * n_rep + n`` reads kv head ``g``). The chunked path needs
+    ``hkv == hq``.
 
     Perf knobs (EXPERIMENTS.md §Perf): ``score_dtype=bf16`` stores the s^2
     score/prob tensors in bf16 (denominator still accumulated in f32);
     ``score_spec`` applies a sharding constraint to the score tensor
     (sequence-parallel attention: P(dp, None, 'model', None)).
     """
-    if chunked and k.shape[1] > chunk:
+    b, sq, hq, _ = q.shape
+    skv, hkv = k.shape[1], k.shape[2]
+    if hq % hkv:
+        raise ValueError(f"{hkv} kv heads do not divide {hq} q heads")
+    n_rep = hq // hkv
+    if chunked and skv > chunk:
         if return_probs:
             raise ValueError("return_probs is unsupported on the chunked "
                              "path (probs are never materialised)")
+        if n_rep > 1:
+            raise ValueError("the chunked path needs as many kv heads as "
+                             "q heads: repeat_kv first")
         return _attend_chunked(q, k, v, scale=scale, causal=causal,
                                q_offset=q_offset, kv_len=kv_len, chunk=chunk)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(score_dtype) * scale
-    sq, skv = q.shape[1], k.shape[1]
+    # (b, hkv, n_rep * sq, skv) -> (b, hq, sq, skv) in repeat_kv's order
+    s = jnp.einsum("bmgd,bkgd->bgmk", group_q_heads(q, hkv), k
+                   ).reshape(b, hq, sq, skv)
+    s = s.astype(score_dtype) * scale
     neg = jnp.asarray(-1e30, score_dtype)
     if causal:
         q_pos = jnp.arange(sq)[:, None] + q_offset
@@ -77,7 +97,8 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         e = jnp.exp(s - m)
         denom = jnp.sum(e.astype(jnp.float32), axis=-1, keepdims=True)
         p = (e / jnp.maximum(denom, 1e-30).astype(score_dtype)).astype(v.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    out = ungroup_q_heads(jnp.einsum(
+        "bgmk,bkgd->bmgd", p.reshape(b, hkv, n_rep * sq, skv), v), sq)
     return (out, p) if return_probs else out
 
 

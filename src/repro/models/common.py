@@ -86,6 +86,26 @@ def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     return jnp.broadcast_to(x[:, :, :, None, :], (b, s, kv, n_rep, d)).reshape(b, s, kv * n_rep, d)
 
 
+def group_q_heads(x: jnp.ndarray, hkv: int) -> jnp.ndarray:
+    """(b, s, hq, d) -> (b, n_rep * s, hkv, d) for a per-kv-group
+    contraction: kv head g gets the rows of its q heads g * n_rep + n
+    (repeat_kv's order), row n * s + i for position i, so it is read once
+    for all of them. With hkv == hq nothing moves."""
+    b, s, hq, d = x.shape
+    n_rep = hq // hkv
+    return x.reshape(b, s, hkv, n_rep, d).transpose(0, 3, 1, 2, 4).reshape(
+        b, n_rep * s, hkv, d)
+
+
+def ungroup_q_heads(x: jnp.ndarray, s: int) -> jnp.ndarray:
+    """The inverse of :func:`group_q_heads`: (b, n_rep * s, hkv, d) ->
+    (b, s, hq, d)."""
+    b, m, hkv, d = x.shape
+    n_rep = m // s
+    return x.reshape(b, n_rep, s, hkv, d).transpose(0, 2, 3, 1, 4).reshape(
+        b, s, hkv * n_rep, d)
+
+
 def kv_group_mean(w: jnp.ndarray, hkv: int) -> jnp.ndarray:
     """(..., hq, K) per-q-head key weights -> (..., hkv, K) mean per kv
     group. The inverse reduction of :func:`repeat_kv`: consecutive q heads

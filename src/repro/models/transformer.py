@@ -387,7 +387,7 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     updated ``k``/``v`` pools plus ``kt``/``mass`` when those were given.
     """
     from repro.models.attention import attend
-    from repro.models.common import apply_rope, repeat_kv
+    from repro.models.common import apply_rope, group_q_heads, ungroup_q_heads
     if cfg.mrope:
         raise ValueError("paged decode does not support M-RoPE streams")
     if (slot_ranks is None) != (basis is None):
@@ -406,7 +406,6 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
     dh = cfg.resolved_head_dim()
     d = cfg.d_model
-    n_rep = hq // hkv
     ps = pool_k.shape[2]
     n_pp = page_table.shape[1]
     M = n_pp * ps
@@ -505,11 +504,11 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
             # contraction then ignores the matching k-factor columns, so
             # the k side needs no mask)
             with jax.named_scope("rank_proj"):
-                b_q = (jnp.repeat(basis_l, n_rep, axis=1) if n_rep > 1
-                       else basis_l)                     # (ns, hq, d, r)
-                q_proj = (jnp.einsum("bshd,bhdr->bshr",
-                                     q.astype(jnp.float32), b_q)
-                          * col_ok[:, None, None, :]).astype(x.dtype)
+                # each kv head's basis serves its n_rep q heads in place
+                q_proj = ungroup_q_heads(jnp.einsum(
+                    "bmgd,bgdr->bmgr",
+                    group_q_heads(q.astype(jnp.float32), hkv), basis_l), C)
+                q_proj = (q_proj * col_ok[:, None, None, :]).astype(x.dtype)
                 if ktp is None:
                     k_fac = jnp.einsum("bmhd,bhdr->bmhr",
                                        k_dense.astype(jnp.float32),
@@ -547,8 +546,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                                                else probs[:, :, None])
                 o = jnp.swapaxes(o, 1, 2)                    # (ns, C, hq, dh)
             else:
-                res = attend(q_use, repeat_kv(k_use, n_rep), repeat_kv(vg, n_rep),
-                             scale=scale, causal=False,
+                # grouped GQA: each kv head is read once for its n_rep
+                # q heads, never repeated to hq heads
+                res = attend(q_use, k_use, vg, scale=scale, causal=False,
                              kv_len=kv_len_q[:, None, :, None],
                              score_dtype=score_dtype,
                              return_probs=want_probs)

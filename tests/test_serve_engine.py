@@ -212,10 +212,16 @@ def test_mixed_step_writes_pools_in_place(scan_layers):
 # fused paged decode == monolithic decode
 # ---------------------------------------------------------------------------
 
-def test_paged_step_matches_monolithic_decode():
+@pytest.mark.parametrize("n_kv", [None, 2], ids=["mha", "gqa"])
+def test_paged_step_matches_monolithic_decode(n_kv):
     """Mode 'off': the fused per-row step must reproduce decode_step_dense
-    for each slot independently, including slots at different lengths."""
+    for each slot independently, including slots at different lengths.
+    The GQA case (2 kv heads for 4 q heads) checks the step's grouped
+    contraction against the monolithic decode, which repeats K/V."""
     cfg = _cfg("off")
+    if n_kv is not None:
+        cfg = cfg.with_(num_kv_heads=n_kv)
+        assert n_kv < cfg.num_heads
     fns = get_model(cfg)
     params = fns.init(RNG)
     lens = [6, 11]

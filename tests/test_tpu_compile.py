@@ -10,7 +10,8 @@ copy or slice of a whole pool, and little scratch memory.
 The engine's fused steps and its segment decision must fit one chip at
 the InternLM2-20B long-context deployment (4 of its 48 layers at
 published widths, bfloat16, 8 slots of 12288 positions, the factor pool
-on) with room to spare.
+on) with room to spare, and its fused steps must read each of the 8 kv
+heads once for its 6 q heads instead of repeating K and V to 48 heads.
 
 Nothing runs: these catch what interpret mode cannot (tiling, fast-memory
 budget) at no chip time. The topology is described inside a fixture, so
@@ -27,6 +28,7 @@ from repro.configs.base import RankConfig
 from repro.core.drrl import init_agent
 from repro.kernels.decode_attn import flash_decode
 from repro.models.api import get_model
+from repro.models.attention import attend
 from repro.serve import PagedKVCache, ServeEngine
 
 HQ = HKV = 12
@@ -132,8 +134,8 @@ def test_mixed_step_keeps_pools_in_place_for_v5e(one_chip):
 
 
 # One v5e holds 16 GB. The longctx steps compile to 7.48 GB of
-# arguments (weights 5.4, K/V pools 1.6, factor pool 0.45) and 3.03 /
-# 3.18 GB of temporaries (pure decode / mixed), ``decide`` to 1.28 +
+# arguments (weights 5.4, K/V pools 1.6, factor pool 0.45) and 1.31 /
+# 1.72 GB of temporaries (pure decode / mixed), ``decide`` to 1.28 +
 # 0.43 GB. The limits leave the rest of the chip for the eager programs
 # and fail a change that brings back pool-sized temporaries (a K or V
 # pool is 0.8 GB; when the layer scan sliced and restacked the pools
@@ -190,8 +192,13 @@ def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
     return out
 
 
-def test_longctx_steps_and_decide_fit_one_v5e(one_chip):
-    progs = _longctx_programs(one_chip)
+@pytest.fixture(scope="module")
+def longctx_programs(one_chip):
+    return _longctx_programs(one_chip)
+
+
+def test_longctx_steps_and_decide_fit_one_v5e(longctx_programs):
+    progs = longctx_programs
     for name in ("step", "mixed"):
         m = progs[name].memory_analysis()
         total = m.argument_size_in_bytes + m.temp_size_in_bytes
@@ -200,3 +207,82 @@ def test_longctx_steps_and_decide_fit_one_v5e(one_chip):
             f" + {m.temp_size_in_bytes / 1e9:.2f} GB of temporaries")
     temp = progs["decide"].memory_analysis().temp_size_in_bytes
     assert temp < DECIDE_TEMP_LIMIT, f"decide: {temp / 1e9:.2f} GB"
+
+
+# GQA 6:1 at the longctx deployment: 48 q heads read 8 kv heads of 128
+# (64 factor columns). Before the grouped contraction the steps repeated
+# V and the K factors to 48 heads, [8,12288,8,6,128] and [8,12288,8,6,64],
+# each layer of each step, and the pure-decode step took 3.03 GB of
+# temporaries.
+LONGCTX_HQ, LONGCTX_HKV, LONGCTX_LEN = 48, 8, 12288
+LONGCTX_WIDTHS = (64, 128)
+STEP_TEMP_LIMIT = 2e9
+
+
+def test_longctx_steps_read_each_kv_head_once(longctx_programs):
+    """No tensor of either fused step spans the cache positions, a head
+    width and the q heads, whether as 48 heads or as the (8, 6) pair of
+    kv head and repeat: K and V are contracted per kv group, never
+    repeated to the q heads."""
+    group = (LONGCTX_HKV, LONGCTX_HQ // LONGCTX_HKV)
+    for name in ("step", "mixed"):
+        repeated = set()
+        for m in re.finditer(r"\[([\d,]+)\]",
+                             longctx_programs[name].as_text()):
+            dims = [int(x) for x in m.group(1).split(",")]
+            if (LONGCTX_LEN in dims
+                    and any(w in dims for w in LONGCTX_WIDTHS)
+                    and (LONGCTX_HQ in dims
+                         or group in zip(dims, dims[1:]))):
+                repeated.add(m.group(0))
+        assert not repeated, f"{name}: K/V repeated to q heads: {repeated}"
+    temp = longctx_programs["step"].memory_analysis().temp_size_in_bytes
+    assert temp < STEP_TEMP_LIMIT, f"step: {temp / 1e9:.2f} GB of temporaries"
+
+
+def _mha_attend(q, k, v, kv_len, scale):
+    """``attend``'s MHA contraction before it learned GQA: the same masks
+    and softmax chain over (b, h, sq, skv) scores, one kv head per q head."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    valid = jnp.arange(k.shape[1])[None, None, None, :] < kv_len
+    s = jnp.where(valid, s, jnp.asarray(-1e30, jnp.float32))
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v), p
+
+
+def _hlo_ops(compiled):
+    """The compiled program's instructions, names and metadata dropped."""
+    ops = []
+    for line in compiled.as_text().splitlines():
+        if (" = " not in line or "file_name_id" in line
+                or line.startswith("HloModule")):
+            continue
+        line = re.sub(r",? ?(metadata|stack_frame_id|origin|backend_config|"
+                      r"frontend_attributes)=(\{[^}]*\}|\S+)", "", line)
+        ops.append(re.sub(r"%[\w.\-]+", "%", line).strip())
+    return sorted(ops)
+
+
+@pytest.mark.parametrize("sq", [1, 16])
+def test_grouped_attend_compiles_as_mha_at_one_kv_head_per_q_head(one_chip,
+                                                                   sq):
+    """With as many kv heads as q heads (drrl-paper: 12 of 64, 32 rows of
+    2048 positions, one decode token or a 16-token prefill chunk) the
+    grouped contraction's size-1 group axis leaves no trace: ``attend``
+    compiles to the same program as the plain MHA contraction."""
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n, skv = 32, 2048
+    args = (spec((n, sq, HQ, DH)), spec((n, skv, HKV, DH)),
+            spec((n, skv, HKV, DH)), spec((n, 1, sq, 1), jnp.int32))
+
+    def grouped(q, k, v, kv_len):
+        return attend(q, k, v, scale=DH ** -0.5, causal=False, kv_len=kv_len,
+                      return_probs=True)
+
+    def mha(q, k, v, kv_len):
+        return _mha_attend(q, k, v, kv_len, DH ** -0.5)
+
+    got, want = (_hlo_ops(jax.jit(f).lower(*args).compile())
+                 for f in (grouped, mha))
+    assert got == want
