@@ -41,16 +41,12 @@ def _get(d, path):
 #   "eq_abs"    fresh == band exactly (deterministic counters only)
 #   "info"      reported, never gated (wall-clock on shared runners)
 CHECKS = [
-    ("chunked_prefill.parity", "flag", None,
-     "chunked admission is token-identical to one-shot"),
     ("prefix_cache.parity", "flag", None,
      "prefix-cache hit is token-identical to cold admission"),
     ("factor_cache.parity_full_rank", "flag", None,
      "factored decode matches dense at full rank"),
     ("speedup", "min_ratio", 0.20,
      "continuous batching vs sequential (smoke under-saturates the slots)"),
-    ("chunked_prefill.interleaved.ttft_p50_ms", "max_ratio", 4.0,
-     "chunked-prefill time-to-first-token, p50"),
     ("prefix_cache.prefill_token_reduction", "min_ratio", 0.5,
      "prefill tokens cut by shared-prefix reuse"),
     ("prefix_cache.cached.hit_rate", "min_ratio", 0.7,

@@ -21,14 +21,7 @@ low-rank serving grid (r_max/d of the dense K bytes; the wall-clock gap
 only opens on accelerators where decode is KV-bandwidth bound — CPU toy
 scale is dispatch-bound).
 
-A fourth section compares **interleaved (chunked) vs blocking (one-shot)
-prefill admission** on the same staggered workload: token parity between
-the two admission modes is asserted, and per-request TTFT p50/p95 plus
-the decode-stall seconds (wall time spent in monolithic prefills while
-other streams had decode work pending — identically zero for chunked
-admission) land in BENCH_serve.json.
-
-A fifth section drives a **shared-system-prompt workload** through the
+A fourth section drives a **shared-system-prompt workload** through the
 prefix cache (repro.serve.prefix) and its cache-off twin: prefix-hit vs
 cold token parity and the refcount invariant are asserted, and the
 section records hit rate, prefill tokens computed per request (>= 2x
@@ -47,6 +40,7 @@ rank) and the replay validity land in the JSON for check_bench to gate.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import subprocess
 import sys
@@ -94,13 +88,13 @@ def obs_overhead(cfg, params, workload, n_slots: int, max_len: int):
     vs ON. Token parity is asserted; tok/s both ways and the ratio are
     recorded as ``info`` rows so drift is visible in review without
     gating CI on sub-millisecond host timing noise."""
-    from repro.serve import Request, ServeEngine
+    from repro.serve import EngineConfig, Request, ServeEngine
 
     def drive(obs_trace):
-        eng = ServeEngine(cfg, params, n_slots=n_slots, max_len=max_len,
-                          page_size=16, segment_len=8,
-                          max_new_cap=max(w["max_new"] for w in workload),
-                          prefill_chunk=8, obs_trace=obs_trace)
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=n_slots, max_len=max_len, page_size=16, segment_len=8,
+            max_new_cap=max(w["max_new"] for w in workload), prefill_chunk=8,
+            obs_trace=obs_trace))
         for w in workload:
             eng.submit(Request(**w))
         eng.warmup()
@@ -144,16 +138,17 @@ def factor_compare(cfg, params, workload, n_slots: int, max_len: int):
     score-contraction read-bytes-per-token quantify the r/d bandwidth cut.
     """
     from repro.configs.base import RankConfig
-    from repro.serve import Request, ServeEngine
+    from repro.serve import EngineConfig, Request, ServeEngine
 
     dh = cfg.resolved_head_dim()
     hkv = cfg.num_kv_heads
 
     def drive(rank_cfg, factor):
-        eng = ServeEngine(cfg.with_(rank=rank_cfg), params, n_slots=n_slots,
-                          max_len=max_len, page_size=16, segment_len=8,
-                          max_new_cap=max(w["max_new"] for w in workload),
-                          factor_cache=factor)
+        ecfg = EngineConfig(n_slots=n_slots, max_len=max_len, page_size=16,
+                            segment_len=8,
+                            max_new_cap=max(w["max_new"] for w in workload),
+                            factor_cache=factor)
+        eng = ServeEngine(cfg.with_(rank=rank_cfg), params, config=ecfg)
         for w in workload:
             eng.submit(Request(**w))
         eng.warmup()
@@ -193,55 +188,6 @@ def factor_compare(cfg, params, workload, n_slots: int, max_len: int):
     }
 
 
-def chunked_compare(cfg, params, workload, n_slots: int, max_len: int,
-                    chunk: int = 8):
-    """Interleaved (chunked) vs blocking (one-shot) prefill admission.
-
-    Both engines run the identical staggered workload with per-step
-    blocking (honest walls). Token parity between the admission modes is
-    asserted; per-request TTFT (admission -> token 0) p50/p95 and the
-    blocking path's decode-stall seconds are reported.
-    """
-    from repro.serve import Request, ServeEngine
-
-    def drive(prefill_chunk):
-        eng = ServeEngine(cfg, params, n_slots=n_slots, max_len=max_len,
-                          page_size=16, segment_len=8,
-                          max_new_cap=max(w["max_new"] for w in workload),
-                          prefill_chunk=prefill_chunk, time_per_token=True)
-        for w in workload:
-            eng.submit(Request(**w))
-        eng.warmup()
-        outs = eng.run()
-        ttft = np.asarray(eng.first_token_s) * 1e3          # ms
-        return outs, {
-            "ttft_p50_ms": float(np.percentile(ttft, 50)),
-            "ttft_p95_ms": float(np.percentile(ttft, 95)),
-            # same TTFTs through the obs histogram (fixed-bucket,
-            # interpolated): the serving-path estimate an exporter
-            # scrape would see, reported beside the exact percentile
-            "ttft_hist_p50_ms": eng.obs.ttft_hist.percentile(50) * 1e3,
-            "ttft_hist_p95_ms": eng.obs.ttft_hist.percentile(95) * 1e3,
-            "decode_stall_s": eng.stats["stall_s"],
-            "mixed_steps": eng.stats["mixed_steps"],
-            "steps": eng.stats["steps"],
-            "tok_per_s": eng.stats["tokens_decoded"]
-                         / max(eng.stats["decode_s"], 1e-9),
-        }
-
-    outs_b, blocking = drive(None)
-    outs_i, interleaved = drive(chunk)
-    parity = all(np.array_equal(outs_b[w["rid"]], outs_i[w["rid"]])
-                 for w in workload)
-    assert parity, "chunked-prefill decode diverged from one-shot prefill"
-    return {
-        "parity": parity,
-        "chunk": chunk,
-        "interleaved": interleaved,
-        "blocking": blocking,
-    }
-
-
 def prefix_compare(cfg, params, n_slots: int, max_len: int,
                    smoke: bool = False):
     """Shared-system-prompt traffic with the prefix cache on vs off.
@@ -254,7 +200,7 @@ def prefix_compare(cfg, params, n_slots: int, max_len: int,
     records hit rate, prefill tokens computed per request (the >= 2x
     reduction headline) and TTFT p50/p95 split hot (prefix hit) vs cold.
     """
-    from repro.serve import Request, ServeEngine
+    from repro.serve import EngineConfig, Request, ServeEngine
 
     rnd = np.random.default_rng(7)
     n_req, shared_len, tail, max_new = (4 if smoke else 8), 32, 8, 8
@@ -269,10 +215,10 @@ def prefix_compare(cfg, params, n_slots: int, max_len: int,
         # time_per_token blocks every fused step, so TTFT is a true wall
         # (free-running dispatch would timestamp the enqueue, not the
         # token)
-        eng = ServeEngine(cfg, params, n_slots=n_slots, max_len=max_len,
-                          page_size=16, segment_len=8, max_new_cap=max_new,
-                          prefill_chunk=16, prefix_cache=prefix,
-                          time_per_token=True)
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=n_slots, max_len=max_len, page_size=16, segment_len=8,
+            max_new_cap=max_new, prefill_chunk=16, prefix_cache=prefix,
+            time_per_token=True))
         # two passes: the first also compiles the admission-time control
         # ops (snapshot slices, mass rehydration, CoW copies) that
         # warmup() cannot reach; the second pass is the measurement —
@@ -365,17 +311,17 @@ def spec_compare(cfg, params, workload, n_slots: int, max_len: int,
     buys is per-dispatch: ~6x fewer fused steps (and host syncs) per
     decoded token, which converts to wall-clock exactly where decode is
     dispatch- or KV-read-bound."""
-    from repro.serve import Request, ServeEngine
+    from repro.serve import EngineConfig, Request, ServeEngine
 
     workload = [dict(w, max_new=32) for w in workload]
     max_len = max(max_len, 32 + 32 + 16)  # longest prompt + budget + slack
 
     def build(speculative):
-        return ServeEngine(cfg, params, n_slots=n_slots, max_len=max_len,
-                           page_size=16, segment_len=8,
-                           max_new_cap=max(w["max_new"] for w in workload),
-                           prefill_chunk=8, speculative=speculative,
-                           draft_k=draft_k, draft_rank_frac=draft_rank_frac)
+        return ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=n_slots, max_len=max_len, page_size=16, segment_len=8,
+            max_new_cap=max(w["max_new"] for w in workload), prefill_chunk=8,
+            speculative=speculative, draft_k=draft_k,
+            draft_rank_frac=draft_rank_frac))
 
     def pass_(eng, warmed):
         if warmed:
@@ -450,7 +396,7 @@ def learned_policy_compare(cfg, params, smoke: bool = False,
     import tempfile
 
     from repro.configs.base import RankConfig
-    from repro.serve import Request, ServeEngine
+    from repro.serve import EngineConfig, Request, ServeEngine
     from repro.serve.traces import TraceReader, TraceRecorder
     from repro.serve.workloads import build, make_workload, workload_names
     from repro.train.serve_policy import load_policy, train_serve_policy
@@ -469,11 +415,11 @@ def learned_policy_compare(cfg, params, smoke: bool = False,
         served = 0
         valid = True
         for spec in specs:
-            eng = ServeEngine(run_cfg, params, policy_params, n_slots=4,
-                              max_len=96, page_size=16, segment_len=8,
-                              max_new_cap=max_new, prefill_chunk=8,
-                              record_traces=recorder,
-                              **spec.engine_overrides)
+            ecfg = EngineConfig(n_slots=4, max_len=96, page_size=16,
+                                segment_len=8, max_new_cap=max_new,
+                                prefill_chunk=8, record_traces=recorder,
+                                **spec.engine_overrides)
+            eng = ServeEngine(run_cfg, params, policy_params, config=ecfg)
             for r in build(spec):
                 eng.submit(r)
             outs = eng.run()
@@ -637,8 +583,8 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
     from repro.configs import get_config
     from repro.configs.base import RankConfig
     from repro.models.api import get_model
-    from repro.serve import Request, ServeEngine
-    from repro.serve.api import Engine, EngineConfig, SamplingParams
+    from repro.serve import EngineConfig, Request, ServeEngine
+    from repro.serve.api import Engine, SamplingParams
 
     n_requests, max_new = (4, 8) if smoke else (8, 16) if quick else (16, 24)
     if smoke:
@@ -662,8 +608,9 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
     # -- continuous batching --------------------------------------------
     # throughput runs: free-running dispatch (no per-step blocking);
     # best-of-N because the decode window is sub-second at this scale
-    eng = ServeEngine(cfg, params, n_slots=n_slots, max_len=max_len,
-                      page_size=16, segment_len=8, max_new_cap=max_new)
+    ecfg = EngineConfig(n_slots=n_slots, max_len=max_len, page_size=16,
+                        segment_len=8, max_new_cap=max_new)
+    eng = ServeEngine(cfg, params, config=ecfg)
     es = None
     compile_s = 0.0
     for rep in range(repeats):
@@ -680,10 +627,11 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
     # latency run: same workload, blocking each fused step for honest
     # per-token wall times (the blocking itself costs throughput, so the
     # two metrics come from separate runs over identical requests)
-    eng.reset()
-    eng.time_per_token = True
+    eng = ServeEngine(cfg, params,
+                      config=dataclasses.replace(ecfg, time_per_token=True))
     for w in workload:
         eng.submit(Request(**w))
+    eng.warmup()
     eng.run()
     lat = np.asarray(eng.token_latencies) * 1e3        # ms per decoded token
     engine_res = {
@@ -692,22 +640,20 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
         "p95_ms": float(np.percentile(lat, 95)) if lat.size else None,
         "first_token_s_mean": float(np.mean(eng.first_token_s))
                               if eng.first_token_s else None,
-        "decode_s": es["decode_s"], "prefill_s": es["prefill_s"],
+        "decode_s": es["decode_s"],
         "compile_s": es["compile_s"], "steps": es["steps"],
         "tokens_decoded": es["tokens_decoded"], "n_slots": n_slots,
     }
 
     # -- sequential per-request lock-step (1-slot api.Engine) -----------
     def seq_engine(timed: bool) -> Engine:
-        return Engine(cfg, params, config=EngineConfig(
-            n_slots=1, max_len=max_len, page_size=16, segment_len=8,
-            max_new_cap=max_new, prefill_chunk=None, sampling=False,
-            time_per_token=timed))
+        return Engine(cfg, params, config=dataclasses.replace(
+            ecfg, n_slots=1, time_per_token=timed))
 
     seq_server = seq_engine(False)
     best = None
     for _ in range(repeats):
-        seq_decode_s = seq_prefill_s = seq_compile_s = 0.0
+        seq_decode_s = seq_compile_s = 0.0
         seq_tokens = 0
         for w in workload:
             seq_server.reset()
@@ -717,11 +663,10 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
             seq_server.run()
             s = seq_server.stats
             seq_decode_s += s["decode_s"]
-            seq_prefill_s += s["prefill_s"]
             seq_tokens += s["tokens_decoded"]
         if best is None or seq_decode_s < best[0]:
-            best = (seq_decode_s, seq_prefill_s, seq_compile_s, seq_tokens)
-    seq_decode_s, seq_prefill_s, seq_compile_s, seq_tokens = best
+            best = (seq_decode_s, seq_compile_s, seq_tokens)
+    seq_decode_s, seq_compile_s, seq_tokens = best
     # sequential latency pass: same per-step blocking the engine's latency
     # run uses, so both p50/p95 are true per-token walls
     seq_lat = []
@@ -737,17 +682,13 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
         "tok_per_s": seq_tokens / max(seq_decode_s, 1e-9),
         "p50_ms": float(np.percentile(seq_lat, 50)) if seq_lat.size else None,
         "p95_ms": float(np.percentile(seq_lat, 95)) if seq_lat.size else None,
-        "decode_s": seq_decode_s, "prefill_s": seq_prefill_s,
+        "decode_s": seq_decode_s,
         "compile_s": seq_compile_s, "tokens_decoded": seq_tokens,
     }
 
     # -- factor-form cache: parity + read bandwidth ---------------------
     fc_workload = workload[:4] if not smoke else workload
     factor_res = factor_compare(cfg, params, fc_workload,
-                                n_slots=min(n_slots, 4), max_len=max_len)
-
-    # -- chunked (interleaved) vs one-shot (blocking) admission ---------
-    chunk_res = chunked_compare(cfg, params, workload,
                                 n_slots=min(n_slots, 4), max_len=max_len)
 
     # -- shared-prefix KV reuse: hit rate, prefill cut, hot/cold TTFT ---
@@ -777,7 +718,6 @@ def run(quick: bool = False, smoke: bool = False, n_slots: int = 8,
         "sequential": seq_res,
         "speedup": engine_res["tok_per_s"] / max(seq_res["tok_per_s"], 1e-9),
         "factor_cache": factor_res,
-        "chunked_prefill": chunk_res,
         "prefix_cache": prefix_res,
         "speculative": spec_res,
         "router": router_res,
@@ -820,13 +760,6 @@ def main():
           f"K-read/token {lo['factored']['k_read_bytes_per_token']}B vs "
           f"{lo['dense']['k_read_bytes_per_token']}B dense "
           f"(ratio {lo['read_ratio']:.2f} = r{lo['r_keep']}/d{lo['dh']})")
-    cp = res["chunked_prefill"]
-    ci, cb = cp["interleaved"], cp["blocking"]
-    print(f"chunked    : parity {cp['parity']}  TTFT p50/p95 "
-          f"{ci['ttft_p50_ms']:.1f}/{ci['ttft_p95_ms']:.1f} ms interleaved "
-          f"vs {cb['ttft_p50_ms']:.1f}/{cb['ttft_p95_ms']:.1f} ms blocking; "
-          f"decode stall {ci['decode_stall_s']:.2f}s vs "
-          f"{cb['decode_stall_s']:.2f}s")
     px = res["prefix_cache"]
     hot = px["cached"]["ttft_hot"] or {"p50_ms": float("nan")}
     cold = px["baseline"]["ttft_cold"]

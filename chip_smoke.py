@@ -127,7 +127,7 @@ def fused_step_texts(core) -> dict:
     """Compiled text of the engine's plain and mixed fused steps, lowered
     with the engine's own live state."""
     import jax.numpy as jnp
-    c, ns = core.cache, core.n_slots
+    c, ns = core.cache, core.config.n_slots
     args = (core.params, c.k_pool, c.v_pool, c.kt_pool, c.mass_pool,
             core._pt_dev, core.tokens, core._lens_dev, c.ranks, c.basis,
             jnp.zeros((ns,), bool), core.out_buf, core._plen_dev,

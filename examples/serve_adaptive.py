@@ -8,13 +8,14 @@ decode executable (factor padding + rank masking — see repro.serve).
 import argparse
 
 import jax
+import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import RankConfig
 from repro.core.drrl import init_agent
 from repro.data.synthetic import SyntheticLM
-from repro.launch.serve import AdaptiveServer
 from repro.models.api import get_model
+from repro.serve import Engine, EngineConfig, SamplingParams
 from repro.train.rl import train_agent
 
 
@@ -42,17 +43,24 @@ def main():
         policy, _ = train_agent(cfg, params, policy, data, bc_steps=4,
                                 ppo_steps=4, ppo_epochs=1)
 
-    server = AdaptiveServer(cfg, params, policy,
-                            max_len=args.prompt_len + args.tokens + 8)
-    prompts = jax.random.randint(jax.random.PRNGKey(1),
-                                 (args.batch, args.prompt_len), 0,
-                                 cfg.vocab_size)
-    res = server.generate(prompts, args.tokens, segment_len=args.segment)
-    print(f"decoded {res['tokens'].shape[1]} tokens x {args.batch} streams "
-          f"at {res['tok_per_s']:.1f} tok/s "
-          f"(compile {res['compile_s']:.2f}s, prefill {res['prefill_s']:.2f}s)")
-    print(f"rank schedule (per step, per stream): {res['ranks']}")
-    buckets = sorted({r for step in res['ranks'] for r in step if r >= 0})
+    eng = Engine(cfg, params, policy, config=EngineConfig(
+        n_slots=args.batch, max_len=args.prompt_len + args.tokens + 8,
+        segment_len=args.segment, max_new_cap=args.tokens))
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0,
+        cfg.vocab_size))
+    handles = [eng.submit(p, SamplingParams(max_new=args.tokens))
+               for p in prompts]
+    eng.warmup()
+    eng.run()
+    tokens = np.stack([h.result() for h in handles])
+    s = eng.stats
+    ranks = [r.tolist() for r in eng.core.ranks_per_step()]
+    print(f"decoded {tokens.shape[1]} tokens x {args.batch} streams "
+          f"at {s['tokens_decoded'] / max(s['decode_s'], 1e-9):.1f} tok/s "
+          f"(compile {s['compile_s']:.2f}s)")
+    print(f"rank schedule (per step, per stream): {ranks}")
+    buckets = sorted({r for step in ranks for r in step if r >= 0})
     print(f"rank buckets exercised: {buckets} (one fused executable)")
 
 

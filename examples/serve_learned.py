@@ -29,7 +29,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import RankConfig
 from repro.models.api import get_model
-from repro.serve import Request, ServeEngine
+from repro.serve import EngineConfig, Request, ServeEngine
 from repro.serve.traces import TraceReader, TraceRecorder
 from repro.serve.workloads import build, make_workload, workload_names
 from repro.train.serve_policy import load_policy, train_serve_policy
@@ -62,11 +62,11 @@ def main():
     def serve_suite(run_cfg, policy_params, recorder):
         total = 0
         for spec in specs:
-            eng = ServeEngine(run_cfg, params, policy_params, n_slots=4,
-                              max_len=96, page_size=16, segment_len=8,
-                              max_new_cap=args.tokens, prefill_chunk=8,
-                              record_traces=recorder,
-                              **spec.engine_overrides)
+            ecfg = EngineConfig(n_slots=4, max_len=96, page_size=16,
+                                segment_len=8, max_new_cap=args.tokens,
+                                prefill_chunk=8, record_traces=recorder,
+                                **spec.engine_overrides)
+            eng = ServeEngine(run_cfg, params, policy_params, config=ecfg)
             for r in build(spec):
                 eng.submit(r)
             outs = eng.run()

@@ -62,8 +62,7 @@ def main():
     tps = s["tokens_decoded"] / max(s["decode_s"], 1e-9)
     print(f"\n{args.streams} streams x {args.tokens} tokens at "
           f"{tps:.1f} tok/s (compile {s['compile_s']:.2f}s excluded); "
-          f"chunked prefill: {s['mixed_steps']} mixed steps, "
-          f"stall {s['stall_s'] * 1e3:.1f} ms")
+          f"chunked prefill: {s['mixed_steps']} mixed steps")
     for h in [h0] + rest:
         assert h.done and len(h.result()) == args.tokens
         print(f"  rid {h.rid}: TTFT {h.ttft_s * 1e3:7.1f} ms  "

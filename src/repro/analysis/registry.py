@@ -37,9 +37,8 @@ HOST_ENTRIES: tuple[tuple[str, str], ...] = (
 # reason each is exempt (admission and warmup legitimately block).
 HOST_STOPS: dict[tuple[str, str], str] = {
     ("serve/engine.py", "ServeEngine._admit"):
-        "admission/prefill is control-plane; its one-shot prefill sync is "
-        "measured separately as prefill_s and never runs between decode "
-        "dispatches of live slots",
+        "admission is control-plane: it stages prompts on device and "
+        "runs no model work",
     ("serve/engine.py", "ServeEngine.warmup"):
         "warmup exists to absorb compiles and syncs before serving",
     ("serve/engine.py", "ServeEngine.reset"):

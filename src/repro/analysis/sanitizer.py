@@ -140,7 +140,7 @@ def run_scenario(name: str, *, n_requests: int = 6,
     from repro.configs import get_config
     from repro.configs.base import RankConfig
     from repro.models.api import get_model
-    from repro.serve import Request, ServeEngine
+    from repro.serve import EngineConfig, Request, ServeEngine
 
     grid = (4, 8, 12, 16)
     mode = "learned" if name == "learned_policy" else "adaptive"
@@ -165,9 +165,9 @@ def run_scenario(name: str, *, n_requests: int = 6,
     # the repro.obs contract that hooks are pure host Python
     sampling = name in ("mixed_sampling", "observability")
     kwargs = dict(n_slots=4, max_len=64, page_size=16, segment_len=8,
-                  max_new_cap=max_new, prefill_chunk=8)
+                  max_new_cap=max_new, prefill_chunk=8, sampling=sampling)
     if sampling:
-        kwargs.update(sampling=True, nucleus=True)
+        kwargs.update(nucleus=True)
     elif name == "speculative":
         kwargs.update(speculative=True, draft_k=3, draft_rank_frac=0.25)
     if name == "observability":
@@ -175,7 +175,8 @@ def run_scenario(name: str, *, n_requests: int = 6,
 
     counter = CompileCounter()
     with counter.attached():
-        eng = ServeEngine(cfg, params, policy_params, **kwargs)
+        eng = ServeEngine(cfg, params, policy_params, config=EngineConfig(
+            **kwargs))
 
         # warm phase: compiles are free here
         for w in _workload(n_requests, max_new, seed=0, sampling=sampling):

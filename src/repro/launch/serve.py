@@ -1,10 +1,10 @@
-"""Adaptive serving front-end (thin shim over ``repro.serve.api``).
+"""Small serving CLI over ``repro.serve.api``.
 
 The serving surface lives in ``repro.serve.api``: ``EngineConfig`` +
 ``SamplingParams`` + ``Engine.submit(prompt, params) -> RequestHandle``
-with chunked prefill interleaved into the fused decode step. The
-historical :class:`AdaptiveServer` lock-step wrapper is re-exported from
-there (deprecated) so old imports keep working.
+with chunked prefill interleaved into the fused decode step.
+
+    PYTHONPATH=src python -m repro.launch.serve --reduced --tokens 8
 """
 from __future__ import annotations
 
@@ -15,10 +15,9 @@ import jax
 from repro.configs import get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import get_model
-from repro.serve.api import (AdaptiveServer, Engine, EngineConfig,
-                             SamplingParams)
+from repro.serve.api import Engine, EngineConfig, SamplingParams
 
-__all__ = ["AdaptiveServer", "main"]
+__all__ = ["main"]
 
 
 def main(argv=None):
@@ -29,7 +28,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=64)
     ap.add_argument("--chunk", type=int, default=16,
-                    help="prefill chunk size (0 = legacy one-shot prefill)")
+                    help="prompt tokens consumed per fused step")
     args = ap.parse_args(argv)
     use_compile_cache()
 
@@ -43,7 +42,7 @@ def main(argv=None):
     eng = Engine(cfg, params, policy, config=EngineConfig(
         n_slots=args.batch, max_len=args.prompt_len + args.tokens + 8,
         segment_len=16, max_new_cap=args.tokens,
-        prefill_chunk=args.chunk or None,
+        prefill_chunk=args.chunk,
         sampling=False))      # greedy-only CLI: keep the lean step
     prompts = jax.random.randint(jax.random.PRNGKey(1),
                                  (args.batch, args.prompt_len), 0,
@@ -58,7 +57,7 @@ def main(argv=None):
     tps = s["tokens_decoded"] / max(s["decode_s"], 1e-9)
     ranks = eng.core.ranks_per_step()
     print(f"decoded ({args.batch}, {args.tokens}) at {tps:.1f} tok/s "
-          f"(compile {s['compile_s']:.2f}s, prefill {s['prefill_s']:.2f}s, "
+          f"(compile {s['compile_s']:.2f}s, "
           f"mixed steps {s['mixed_steps']}); "
           f"per-slot rank schedule: {[r.tolist() for r in ranks[:8]]}...")
     print(f"TTFT per request: "

@@ -353,28 +353,9 @@ def mhsa(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray,
     n_rep = hq // hkv
     k_use_r = repeat_kv(k_use, n_rep)
     v_use = repeat_kv(v_full, n_rep)
-    if rank_ctx is not None and rank_ctx.get("collect_mass", False):
-        # per-key attention mass off the same softmax chain the output
-        # uses (no second score pass, honours score_dtype): summed over
-        # valid queries, group-meaned over each kv head's q heads. The
-        # serve prefill seeds its paged mass accumulator with this.
-        o, pr = attend(q_use, k_use_r, v_use, scale=scale, causal=True,
-                       q_offset=q_offset, kv_len=kv_len, chunked=chunked,
-                       score_dtype=score_dtype, score_spec=score_spec,
-                       return_probs=True)
-        prf = pr.astype(jnp.float32)               # (b, hq, sq, skv)
-        mql = rank_ctx.get("mass_q_len")
-        if mql is not None:
-            # padded-bucket prefill: garbage queries beyond the prompt
-            # must not scatter mass back onto real keys
-            q_ok = (jnp.arange(prf.shape[2]) < mql).astype(jnp.float32)
-            prf = prf * q_ok[None, None, :, None]
-        from repro.models.common import kv_group_mean
-        aux["mass"] = kv_group_mean(jnp.sum(prf, axis=2), hkv)
-    else:
-        o = attend(q_use, k_use_r, v_use, scale=scale, causal=True,
-                   q_offset=q_offset, kv_len=kv_len, chunked=chunked,
-                   score_dtype=score_dtype, score_spec=score_spec)
+    o = attend(q_use, k_use_r, v_use, scale=scale, causal=True,
+               q_offset=q_offset, kv_len=kv_len, chunked=chunked,
+               score_dtype=score_dtype, score_spec=score_spec)
     if "_o_full" in aux:
         of, ol = aux.pop("_o_full"), o
         num = jnp.sum(of.astype(jnp.float32) * ol.astype(jnp.float32), axis=(1, 3))

@@ -10,7 +10,7 @@ cutting decode cache memory AND read bandwidth by r/d — on the decode_32k
 cell the KV cache is the dominant memory term after the §Perf split-KV fix,
 so this directly attacks the remaining roofline bound. New tokens are
 projected onto the prefill basis; the basis can be refreshed every segment with
-incremental subspace extension (Eq. 12) — the AdaptiveServer re-decides the
+incremental subspace extension (Eq. 12) — the serving engine re-decides the
 bucket anyway, so a refresh is a bucket switch.
 
 V is kept full here (scores drive the quality trade-off; value truncation is

@@ -109,30 +109,25 @@ def _aux_slim(aux: Dict[str, Any], collect: str) -> Dict[str, Any]:
     keep = {"rank", "delta_a_rel", "fidelity", "aux_loss"}
     if collect == "rl":
         keep |= {"action_idx", "logp", "value", "action_mask", "features",
-                 "logits", "delta_a_grid", "delta_a_norm", "k_s2", "qkv",
-                 "mass"}
+                 "logits", "delta_a_grid", "delta_a_norm", "k_s2", "qkv"}
     return {k: v for k, v in aux.items() if k in keep}
 
 
 def make_rank_ctx(cfg: ModelConfig, *, policy_params=None, rng=None, t=0,
                   greedy=True, compute_fidelity=False, h_t=None,
-                  collect_qkv=False, collect_mass=False, mass_q_len=None):
+                  collect_qkv=False):
     """Build the per-forward rank context (None when mode == 'off', unless
-    qkv/mass capture is requested — the serve prefill collects per-layer
-    k/v and the per-key attention mass from the untouched full-rank
-    forward)."""
+    qkv capture is requested — the RL rollout and the low-rank cache
+    collect per-layer q/k/v from the untouched full-rank forward)."""
     rcfg = cfg.rank
     if rcfg.mode == "off":
-        if collect_qkv or collect_mass:
+        if collect_qkv:
             return {"cfg": rcfg, "rng": rng, "t": t,
-                    "compute_fidelity": False, "collect_qkv": collect_qkv,
-                    "collect_mass": collect_mass, "mass_q_len": mass_q_len}
+                    "compute_fidelity": False, "collect_qkv": True}
         return None
     ctx: Dict[str, Any] = {"cfg": rcfg, "rng": rng, "t": t,
                            "compute_fidelity": compute_fidelity,
-                           "collect_qkv": collect_qkv,
-                           "collect_mass": collect_mass,
-                           "mass_q_len": mass_q_len}
+                           "collect_qkv": collect_qkv}
     if rcfg.mode == "performer":
         from repro.core.baselines import orthogonal_proj
         dh = cfg.resolved_head_dim()
@@ -152,7 +147,6 @@ def forward_dense(cfg: ModelConfig, params, tokens, *, positions=None,
                   policy_params=None, rank_rng=None, rl_t=0, greedy=True,
                   compute_fidelity=False, collect_aux: str = "none",
                   chunked: bool = False, collect_qkv: bool = False,
-                  collect_mass: bool = False, mass_q_len=None,
                   return_hidden: bool = False,
                   extra_embeddings: Optional[jnp.ndarray] = None
                   ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
@@ -174,9 +168,7 @@ def forward_dense(cfg: ModelConfig, params, tokens, *, positions=None,
     rank_ctx0 = make_rank_ctx(cfg, policy_params=policy_params, rng=rank_rng,
                               t=rl_t, greedy=greedy,
                               compute_fidelity=compute_fidelity, h_t=h_t,
-                              collect_qkv=collect_qkv,
-                              collect_mass=collect_mass,
-                              mass_q_len=mass_q_len)
+                              collect_qkv=collect_qkv)
 
     def body(carry, xs):
         x, prev_rank, key = carry
@@ -322,8 +314,8 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     queries per row (1 for decode rows, up to C for a mid-prefill row's
     prompt chunk) and ``prefill_rows`` (n_slots,) bool marks rows that are
     mid-prefill: those attend **full-rank dense** (their segment basis
-    does not exist yet; one-shot-prefill parity requires the untouched
-    forward), causally within the chunk, while decode rows in the same
+    does not exist yet; parity with the plain forward requires the
+    untouched scores), causally within the chunk, while decode rows in the same
     executable keep the factor-projected rank path — the two score reads
     are built at head-dim width (factor columns zero-padded, adding exact
     0.0 terms) and selected per row. Returned logits are the **last valid
@@ -357,10 +349,10 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     Also slot-indexed: mass is per-*stream* state (which queries
     attended), so a shared prefix page receives different mass from each
     sharing slot. A prefill chunk's queries add their causal mass over
-    the full prefix — chunk-by-chunk accumulation reproduces the one-shot
-    prompt seed, so the weighted basis still sees the whole prompt's
-    mass. A cell is reset in-graph the step its position is appended
-    (before the add), so recycled slots never leak a previous occupant's
+    the full prefix — chunk-by-chunk accumulation sums to the whole
+    prompt's causal mass, so the weighted basis still sees all of it. A
+    cell is reset in-graph the step its position is appended (before the
+    add), so recycled slots never leak a previous occupant's
     mass; a prefix-hit slot's matched region is instead re-seeded from
     the tree snapshot at admission and only ever added to here.
 

@@ -49,8 +49,8 @@ _VALID_PH = {"X", "B", "E", "b", "e", "n", "i", "I", "C", "M"}
 
 class Stopwatch:
     """One wall-clock interval, optionally disabled: the shared shape of
-    every timing block in the engine (compile, one-shot prefill,
-    per-step token latency, run wall). ``stop()`` returns the elapsed
+    every timing block in the engine (compile, per-step token latency,
+    run wall). ``stop()`` returns the elapsed
     seconds, or None when constructed disabled — matching the engine's
     historical ``t0 = perf_counter() if enabled else None`` idiom."""
 

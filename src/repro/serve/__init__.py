@@ -1,5 +1,7 @@
 """Continuous-batching serving engine (paper section 4.5.2 at scale).
 
+- config:    EngineConfig, the one engine-construction config (shared
+             by api.Engine and engine.ServeEngine, validated once).
 - api:       the public surface — EngineConfig + SamplingParams +
              Engine.submit(prompt, params) -> RequestHandle with
              incremental token streaming and per-request TTFT.
@@ -8,8 +10,7 @@
 - scheduler: request queue, admission (free slots + page reservation,
              chunked prompts tracked mid-prefill), eviction.
 - policy:    slot-indexed segment-level rank decision + eigenbasis refresh
-             (ported from the old AdaptiveServer._decide_rank, no host
-             syncs).
+             (device-resident, no host syncs).
 - prefix:    shared-prefix KV reuse — a token-level radix tree over
              page-granularity prefixes with refcounted page sharing,
              exact attention-mass snapshots, LRU eviction and

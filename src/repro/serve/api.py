@@ -2,20 +2,18 @@
 surface).
 
 The engine-construction knobs live in one :class:`EngineConfig`
-(replacing the historical ``ServeEngine(...)`` kwarg pile), per-request
+(``repro.serve.config``, shared with the core ``ServeEngine``), per-request
 generation knobs in :class:`SamplingParams` (greedy by default; seeded
 temperature / top-k run **in-graph** in the fused step — one executable
 regardless of the mix of greedy and sampled streams), and
 :meth:`Engine.submit` returns a :class:`RequestHandle` that streams
 tokens incrementally (iterator or callback) and records per-request TTFT.
 
-By default prompts are admitted via **chunked prefill**
+Prompts are admitted via **chunked prefill**
 (``EngineConfig.prefill_chunk``): the prompt is consumed a fixed-size
 chunk at a time *inside* the fused decode step, alongside the live decode
 rows — admission never stalls decoding, and prompts of any length share
-one executable instead of one compile per length bucket
-(``prefill_chunk=None`` restores the legacy blocking bucketed prefill).
-Chunked and one-shot admission are token-for-token identical
+one executable. The chunk width does not change the tokens
 (tests/test_serve_chunked.py).
 
     cfg = EngineConfig(n_slots=4, max_len=256)
@@ -33,13 +31,13 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-import warnings
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.serve.config import EngineConfig
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import Request
 
@@ -73,111 +71,6 @@ class SamplingParams:
             raise ValueError(f"top_p must be in (0, 1], got {self.top_p}")
         if self.max_new < 1:
             raise ValueError(f"max_new must be >= 1, got {self.max_new}")
-
-
-@dataclass(frozen=True)
-class EngineConfig:
-    """Engine-level serving knobs (one compile scope).
-
-    ``prefill_chunk``: prompt tokens consumed per fused step while a
-    stream is mid-prefill (chunked prefill interleaved into decode);
-    ``None`` = legacy blocking length-bucketed prefill at admission.
-    ``sampling=False`` compiles the lean greedy-only step (requests with
-    temperature/top_k/top_p then fail fast at submit); ``nucleus=True``
-    additionally compiles the top-p cut — a full-vocab softmax + sort in
-    every fused step, so leave it off unless streams use ``top_p < 1``
-    (such requests fail fast on a nucleus=False engine).
-    ``prefix_cache=True`` turns on shared-prefix KV reuse
-    (``repro.serve.prefix``): finished prompts stay cached in a radix
-    tree, and a request whose prompt starts with a cached prefix shares
-    those pages and prefills only from the divergence point — token
-    parity with cold admission is preserved. Requires chunked prefill;
-    run ``prefill_chunk`` as a multiple of ``page_size`` for a reuse
-    point at every page. ``prefix_pages`` sizes the extra pool headroom
-    kept for cached prefixes (default: one extra slot-set of pages).
-    ``speculative=True`` turns on low-rank self-speculative decoding
-    (``repro.serve.spec``): each fused step drafts ``draft_k`` tokens
-    ahead reading the factor cache at roughly ``draft_rank_frac`` of
-    each row's live rank, verifies all of them in one chunked step at
-    the full current rank, and accepts the longest matching prefix —
-    token-identical to plain decode (greedy and seeded sampling), only
-    faster. Requires chunked prefill. ``snapshot_every`` throttles
-    prefix-cache mass snapshots to every k-th page boundary (probe /
-    match fall back to the nearest earlier snapshot).
-    ``adaptive_draft=True`` (speculative engines only) lets an EWMA of
-    the accept fraction shrink the effective draft length when accept
-    runs collapse (below ``draft_shrink_below``) and restore it when
-    they recover (above ``draft_grow_above``); a fully collapsed stream
-    skips the draft forwards entirely, and ``stats['eff_draft_k']``
-    exposes the live value. Token streams stay exactly identical to
-    plain decode either way. ``record_traces=<dir>`` hooks a
-    :class:`repro.serve.traces.TraceRecorder` into the rank-decision
-    path: per-segment decision features and outcomes land in versioned
-    npz shards for offline policy training
-    (``repro.train.serve_policy``); call ``engine.core.trace.flush()``
-    when serving is done.
-
-    ``obs_trace=True`` turns on :mod:`repro.obs` span/phase tracing
-    (per-request spans + per-step phase timeline, exported as Chrome
-    trace-event JSON via ``engine.obs.chrome_trace()``); the metrics
-    registry itself is always on and costs the loop nothing beyond the
-    host-side counter adds it already did. ``flight_dir=<dir>`` enables
-    flight-recorder dumps: a bounded ring of recent engine events is
-    written there on step exceptions, front-end shutdown and
-    ``reset()`` with requests still in flight."""
-    n_slots: int = 4
-    max_len: int = 256
-    page_size: int = 16
-    segment_len: Optional[int] = None
-    max_new_cap: int = 256
-    prefill_chunk: Optional[int] = 16
-    use_kernel: bool = False
-    drift_threshold: Optional[float] = None
-    factor_cache: Optional[bool] = None
-    prefix_cache: bool = False
-    prefix_pages: Optional[int] = None
-    time_per_token: bool = False
-    sampling: bool = True
-    nucleus: bool = False
-    top_k_cap: int = 64
-    buckets: Optional[Sequence[int]] = None
-    speculative: bool = False
-    draft_k: int = 4
-    draft_rank_frac: float = 0.25
-    snapshot_every: int = 1
-    adaptive_draft: bool = False
-    draft_shrink_below: float = 0.35
-    draft_grow_above: float = 0.6
-    record_traces: Optional[str] = None
-    obs_trace: bool = False
-    flight_dir: Optional[str] = None
-    flight_capacity: int = 256
-
-    def __post_init__(self):
-        if self.flight_capacity < 1:
-            raise ValueError(f"flight_capacity must be >= 1, got "
-                             f"{self.flight_capacity}")
-        if self.prefill_chunk is not None and self.prefill_chunk < 1:
-            raise ValueError(
-                f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
-        if self.max_len < 1 or self.n_slots < 1 or self.page_size < 1:
-            raise ValueError("n_slots/max_len/page_size must be >= 1")
-        if self.prefix_cache and self.prefill_chunk is None:
-            raise ValueError("prefix_cache requires chunked prefill "
-                             "(set prefill_chunk)")
-        if self.speculative and self.prefill_chunk is None:
-            raise ValueError("speculative decode requires chunked prefill "
-                             "(set prefill_chunk)")
-        if self.speculative and self.draft_k < 1:
-            raise ValueError(f"draft_k must be >= 1, got {self.draft_k}")
-        if not 0.0 < self.draft_rank_frac <= 1.0:
-            raise ValueError(f"draft_rank_frac must be in (0, 1], got "
-                             f"{self.draft_rank_frac}")
-        if self.snapshot_every < 1:
-            raise ValueError(f"snapshot_every must be >= 1, got "
-                             f"{self.snapshot_every}")
-        if self.adaptive_draft and not self.speculative:
-            raise ValueError("adaptive_draft requires speculative=True")
 
 
 class EngineStopped(RuntimeError):
@@ -371,30 +264,13 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, policy_params=None, *,
                  config: Optional[EngineConfig] = None, device=None):
         self.config = config or EngineConfig()
-        c = self.config
         self.device = device
         if device is not None:
             import jax
             params = jax.device_put(params, device)
         with self._placed():
-            self.core = ServeEngine(
-                cfg, params, policy_params,
-                n_slots=c.n_slots, max_len=c.max_len, page_size=c.page_size,
-                segment_len=c.segment_len, buckets=c.buckets,
-                max_new_cap=c.max_new_cap, use_kernel=c.use_kernel,
-                drift_threshold=c.drift_threshold,
-                time_per_token=c.time_per_token, factor_cache=c.factor_cache,
-                prefill_chunk=c.prefill_chunk, sampling=c.sampling,
-                nucleus=c.nucleus, top_k_cap=c.top_k_cap,
-                prefix_cache=c.prefix_cache, prefix_pages=c.prefix_pages,
-                speculative=c.speculative, draft_k=c.draft_k,
-                draft_rank_frac=c.draft_rank_frac,
-                snapshot_every=c.snapshot_every,
-                adaptive_draft=c.adaptive_draft,
-                draft_shrink_below=c.draft_shrink_below,
-                draft_grow_above=c.draft_grow_above,
-                record_traces=c.record_traces, obs_trace=c.obs_trace,
-                flight_dir=c.flight_dir, flight_capacity=c.flight_capacity)
+            self.core = ServeEngine(cfg, params, policy_params,
+                                    config=self.config)
         self._handles: Dict[int, RequestHandle] = {}
         self._next_rid = 0
         self._finished_seen = 0
@@ -526,19 +402,16 @@ class Engine:
     def step(self) -> bool:
         """One engine iteration; returns True while work remains.
 
-        Every step accrues its wall time (minus any in-loop prefill) into
-        ``stats['decode_s']``, so throughput stays honest no matter what
-        drives the loop — ``run()``, a ``RequestHandle`` iterator, or an
-        external server loop. Thread-safe: handles on different threads
-        (each blocking in ``result()``/``tokens()``) interleave whole
-        iterations under one lock instead of racing the core state."""
+        Every step accrues its wall time into ``stats['decode_s']``, so
+        throughput stays honest no matter what drives the loop — ``run()``,
+        a ``RequestHandle`` iterator, or an external server loop.
+        Thread-safe: handles on different threads (each blocking in
+        ``result()``/``tokens()``) interleave whole iterations under one
+        lock instead of racing the core state."""
         with self._step_lock, self._placed():
-            stats = self.core.stats
-            p0 = stats["prefill_s"]
             t0 = time.perf_counter()
             self.core.step()
-            stats["decode_s"] += max(
-                time.perf_counter() - t0 - (stats["prefill_s"] - p0), 0.0)
+            self.core.stats["decode_s"] += time.perf_counter() - t0
             for rid, idx, tok in self.core.last_emitted:
                 h = self._handles.get(rid)
                 if h is not None:
@@ -657,77 +530,3 @@ def make_engine(cfg: ModelConfig, params, policy_params=None,
     """Convenience: ``make_engine(cfg, params, n_slots=8, max_len=512)``
     builds the EngineConfig from keyword overrides."""
     return Engine(cfg, params, policy_params, config=EngineConfig(**knobs))
-
-
-class AdaptiveServer:
-    """DEPRECATED lock-step front-end, kept as a compatibility shim over
-    :class:`Engine`: a (b, s0) prompt batch becomes b concurrent streams
-    admitted at step 0, decoded greedily for ``n_tokens`` each, via the
-    legacy one-shot bucketed prefill (token-for-token identical to the
-    chunked default). New code should construct :class:`Engine` with an
-    :class:`EngineConfig` and use ``submit``/``RequestHandle``."""
-
-    def __init__(self, cfg: ModelConfig, params, policy_params=None,
-                 max_len: int = 2048, page_size: int = 16,
-                 use_kernel: bool = False, time_per_token: bool = False,
-                 factor_cache: Optional[bool] = None):
-        warnings.warn(
-            "AdaptiveServer is deprecated; use repro.serve.api.Engine "
-            "(EngineConfig + submit/RequestHandle) instead",
-            DeprecationWarning, stacklevel=2)
-        self.cfg = cfg
-        self.params = params
-        self.policy = policy_params
-        self.max_len = max_len
-        self.page_size = page_size
-        self.use_kernel = use_kernel
-        self.time_per_token = time_per_token
-        self.factor_cache = factor_cache
-        self._engines: Dict[tuple, Engine] = {}
-
-    def _engine(self, n_slots: int, seg: int, max_new: int) -> Engine:
-        key = (n_slots, seg, max_new)
-        eng = self._engines.get(key)
-        if eng is None:
-            eng = Engine(self.cfg, self.params, self.policy,
-                         config=EngineConfig(
-                             n_slots=n_slots, max_len=self.max_len,
-                             page_size=self.page_size, segment_len=seg,
-                             max_new_cap=max_new, prefill_chunk=None,
-                             sampling=False, use_kernel=self.use_kernel,
-                             time_per_token=self.time_per_token,
-                             factor_cache=self.factor_cache))
-            self._engines[key] = eng
-        else:
-            eng.reset()
-        return eng
-
-    def generate(self, prompts, n_tokens: int,
-                 segment_len: Optional[int] = None) -> Dict:
-        """prompts: (b, s0) int32. Greedy decode of n_tokens per stream.
-
-        Returns tokens (b, n_tokens), the per-step per-stream rank record,
-        warm-decode ``tok_per_s`` and the separated ``compile_s`` /
-        ``prefill_s`` costs."""
-        seg = segment_len or self.cfg.rank.segment_len
-        prompts_np = np.asarray(prompts, np.int32)
-        b = prompts_np.shape[0]
-        eng = self._engine(b, seg, n_tokens)
-        handles = [eng.submit(prompts_np[i],
-                              SamplingParams(max_new=n_tokens))
-                   for i in range(b)]
-        eng.warmup()
-        eng.run()
-        tokens = np.stack([h.result() for h in handles])
-        core = eng.core
-        s = core.stats
-        return {
-            "tokens": tokens,
-            "ranks": [r.tolist() for r in core.ranks_per_step()],
-            "tok_per_s": s["tokens_decoded"] / max(s["decode_s"], 1e-9),
-            "compile_s": s["compile_s"],
-            "prefill_s": s["prefill_s"],
-            "token_lat_s": list(core.token_latencies),  # [] unless timed
-            "ttft_s": [h.ttft_s for h in handles],
-            "stats": dict(s),
-        }

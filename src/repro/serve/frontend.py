@@ -24,7 +24,7 @@ This is the "millions of users" layer over the continuous-batching core:
   re-prefilled N times. Prompts with no useful prefix — and affinity
   hits whose replica is overloaded beyond ``depth_slack`` — fall back
   to least-loaded by queue depth. Per-replica and aggregate stats
-  (``depth``, ``hit_rate``, ``stall_s``, ``tok_per_s``) come from
+  (``depth``, ``hit_rate``, ``tok_per_s``) come from
   :meth:`Router.stats`.
 
 - :class:`FleetConfig` — the one runtime-options surface for a fleet
@@ -245,7 +245,7 @@ class FrontEnd:
 
     def stats(self) -> Dict:
         """Live serving stats: queue ``depth``, prefix ``hit_rate``,
-        admission ``stall_s``, decode ``tok_per_s``, speculative
+        decode ``tok_per_s``, speculative
         ``spec_accept_rate`` / ``spec_mean_accept`` (0 on a
         non-speculative engine), plus the raw engine counters under
         ``"engine"``."""
@@ -254,7 +254,6 @@ class FrontEnd:
         return {
             "depth": self.engine.depth,
             "hit_rate": s["prefix_hits"] / max(looked, 1),
-            "stall_s": s["stall_s"],
             "tok_per_s": s["tokens_decoded"] / max(s["decode_s"], 1e-9),
             "tokens_decoded": s["tokens_decoded"],
             "spec_accept_rate":
@@ -389,7 +388,6 @@ class Router:
                 "depth": sum(p["depth"] for p in per),
                 "tokens_decoded": tokens,
                 "hit_rate": hits / max(looked, 1),
-                "stall_s": sum(p["stall_s"] for p in per),
                 "tok_per_s": sum(p["tok_per_s"] for p in per),
             },
             "routed": list(self.routed),

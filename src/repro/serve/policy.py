@@ -1,10 +1,9 @@
 """Per-slot segment-level rank decision (slot-indexed, device-resident).
 
-Port of the old ``AdaptiveServer._decide_rank`` (launch/serve.py) from a
-whole-batch host-side decision to a jitted slot-indexed call: the slot id
-is a traced scalar, so ONE executable serves every slot; the spectral
-solve runs over that slot's live K view for all layers, the guardrail veto
-and annealed threshold apply per slot — and crucially no
+A jitted slot-indexed decision rather than a whole-batch host-side one:
+the slot id is a traced scalar, so ONE executable serves every slot; the
+spectral solve runs over that slot's live K view for all layers, the
+guardrail veto and annealed threshold apply per slot — and crucially no
 ``int(cache["len"])`` host syncs: lengths, previous ranks, bases and
 spectra live on device and the chosen rank / basis / factor pages are
 written back with dynamic-index updates, feeding straight into the fused

@@ -7,18 +7,16 @@ Admission is FIFO over *arrived* requests: a request joins a free slot as
 soon as one exists, its arrival step has passed, and the page pool can
 cover ``prompt_len + max_new`` tokens (under prefix caching the
 ``can_allocate`` hook also matches the prompt against the radix tree and
-shares the hit's pages). Prefill lengths are bucketed (powers of two by
-default) so the prefill executable compiles once per bucket, not once per
-prompt length. Eviction happens on EOS or when ``max_new`` tokens have
-been decoded; releasing a slot *decrements* its pages' refcounts — a page
-returns to the pool when its last reference (sharing slot or cached
-prefix) drops.
+shares the hit's pages). Eviction happens on EOS or when ``max_new``
+tokens have been decoded; releasing a slot *decrements* its pages'
+refcounts — a page returns to the pool when its last reference (sharing
+slot or cached prefix) drops.
 """
 from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, List, Optional, Sequence, Tuple
+from typing import Deque, List, Optional, Tuple
 
 import numpy as np
 
@@ -89,41 +87,16 @@ class SlotState:
         return self.req is not None and self.prefilled < self.prompt_len
 
 
-def prefill_buckets(max_prompt: int, floor: int = 8) -> Tuple[int, ...]:
-    """Power-of-two length buckets covering [1, max_prompt].
-
-    The top bucket is clamped to ``max_prompt``: for non-power-of-two
-    maxima (e.g. 100) the unclamped doubling would emit a bucket (128)
-    larger than any slot can hold, compiling a prefill executable and
-    cache no request is ever allowed to fill."""
-    out, b = [], floor
-    while b < max_prompt:
-        out.append(b)
-        b *= 2
-    out.append(min(b, max_prompt))
-    return tuple(out)
-
-
-def bucket_for(length: int, buckets: Sequence[int]) -> int:
-    for b in buckets:
-        if length <= b:
-            return b
-    raise ValueError(f"prompt length {length} exceeds largest bucket "
-                     f"{buckets[-1]}")
-
-
 class Scheduler:
     """FIFO admission over n_slots decode lanes."""
 
-    def __init__(self, n_slots: int, buckets: Sequence[int]):
+    def __init__(self, n_slots: int):
         self.n_slots = n_slots
-        self.buckets = tuple(buckets)
         self.pending: Deque[Request] = deque()
         self.slots = [SlotState() for _ in range(n_slots)]
         self.finished: List[Tuple[Request, List[int]]] = []
 
     def submit(self, req: Request) -> None:
-        bucket_for(len(req.tokens), self.buckets)   # validate early
         self.pending.append(req)
 
     # -- admission -------------------------------------------------------
@@ -131,10 +104,10 @@ class Scheduler:
     def free_slots(self) -> List[int]:
         return [i for i, s in enumerate(self.slots) if not s.active]
 
-    def admit(self, now: int, can_allocate) -> List[Tuple[int, Request, int]]:
+    def admit(self, now: int, can_allocate) -> List[Tuple[int, Request]]:
         """Assign arrived requests to free slots, FIFO. ``can_allocate(slot,
         total_len) -> bool`` is the page-pool reservation hook. Returns
-        [(slot, request, padded_prefill_bucket)]. A head-of-queue request
+        [(slot, request)]. A head-of-queue request
         that cannot be placed (pages exhausted) blocks the queue — FIFO, no
         starvation via overtaking."""
         placed = []
@@ -153,7 +126,7 @@ class Scheduler:
             st.n_out, st.last_tok = 0, None
             st.latencies = []
             st.accept_lens = []
-            placed.append((slot, req, bucket_for(len(req.tokens), self.buckets)))
+            placed.append((slot, req))
         return placed
 
     def cancel_pending(self, rid: int) -> bool:

@@ -4,7 +4,7 @@ Covers the request/response surface on top of the continuous-batching
 core: EngineConfig / SamplingParams validation (fail fast at submit, not
 silent forever-queueing), RequestHandle streaming (iterator + callback)
 vs batch results, per-request TTFT, seeded sampling reproducibility, and
-the deprecated AdaptiveServer compatibility shim.
+one config surface shared by Engine and the core ServeEngine.
 """
 import jax
 import numpy as np
@@ -199,6 +199,29 @@ def test_top_p_validation(setup):
                config=EngineConfig(nucleus=True, sampling=False))
 
 
+def test_core_and_api_engine_share_one_config(setup):
+    """``ServeEngine(cfg, params)`` and ``Engine(cfg, params)`` build the
+    same engine: both hold ``EngineConfig()``, and its chunk width and
+    sampling switch reach the core — a 20-token prompt takes two mixed
+    steps at the default chunk of 16, and a sampled request is served
+    (not refused as it would be by a greedy-only step), identically."""
+    from repro.serve import ServeEngine
+    cfg, params = setup
+    core = ServeEngine(cfg, params)
+    api = Engine(cfg, params)
+    assert core.config == api.config == api.core.config == EngineConfig()
+    prompt = np.random.default_rng(15).integers(
+        0, cfg.vocab_size, 20).astype(np.int32)
+    outs = []
+    for c in (core, api.core):
+        c.submit(Request(rid=0, tokens=prompt, max_new=4, temperature=0.9,
+                         top_k=8, seed=3))
+        outs.append(c.run()[0])
+        assert c.stats["mixed_steps"] == 2
+        assert c.stats["tokens_decoded"] == 3
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
 def test_top_p_tiny_nucleus_is_greedy(setup):
     """top_p -> 0 shrinks the nucleus to the single most likely token, so
     hot sampling collapses to argmax."""
@@ -272,8 +295,8 @@ def test_top_p_draws_stay_inside_nucleus(setup):
 
 def test_top_p_parity_chunked_vs_oneshot(setup):
     """The nucleus cut runs through the same (seed, output index) PRNG
-    fold: a top-p stream draws identically under chunked and one-shot
-    admission."""
+    fold: a top-p stream draws identically under a narrow chunk and a
+    covering one (the whole prompt in one mixed step)."""
     cfg, params = setup
     prompt = np.random.default_rng(14).integers(
         0, cfg.vocab_size, 11).astype(np.int32)
@@ -285,46 +308,7 @@ def test_top_p_parity_chunked_vs_oneshot(setup):
         eng.run()
         return h.result()
 
-    np.testing.assert_array_equal(run(4), run(None))
-
-
-# ---------------------------------------------------------------------------
-# deprecated AdaptiveServer shim
-# ---------------------------------------------------------------------------
-
-def test_adaptive_server_shim(setup):
-    cfg, params = setup
-    with pytest.warns(DeprecationWarning, match="AdaptiveServer"):
-        from repro.launch.serve import AdaptiveServer
-        server = AdaptiveServer(cfg, params, max_len=48, page_size=8)
-    prompts = np.random.default_rng(5).integers(
-        0, cfg.vocab_size, (2, 10)).astype(np.int32)
-    res = server.generate(prompts, 6, segment_len=8)
-    assert res["tokens"].shape == (2, 6)
-    assert res["compile_s"] > 0.0 and res["stats"]["prefills"] == 2
-    # the shim serves through the same engine: parity with direct api use
-    eng = _engine(cfg, params, prefill_chunk=None, sampling=False,
-                  max_new_cap=6)
-    hs = [eng.submit(prompts[i], SamplingParams(max_new=6))
-          for i in range(2)]
-    eng.run()
-    for i, h in enumerate(hs):
-        np.testing.assert_array_equal(res["tokens"][i], h.result())
-
-
-def test_streaming_oneshot_admission_ordered(setup):
-    """One-shot admission emits token 0 outside the fused step: a
-    streaming consumer must still receive the full, in-order sequence
-    (review fix: tok0 used to never reach the streaming plane)."""
-    cfg, params = setup
-    prompt = np.random.default_rng(6).integers(
-        0, cfg.vocab_size, 10).astype(np.int32)
-    eng = _engine(cfg, params, prefill_chunk=None)
-    seen = []
-    h = eng.submit(prompt, SamplingParams(max_new=8),
-                   on_token=lambda i, t: seen.append((i, t)))
-    eng.run()
-    assert seen == list(enumerate(h.result().tolist()))
+    np.testing.assert_array_equal(run(4), run(len(prompt)))
 
 
 def test_late_consumer_backfills_gap(setup):

@@ -6,8 +6,8 @@ Covers the three layers separately and end-to-end:
   * fused paged decode == monolithic decode (mode 'off'),
   * per-row rank masking == whole-batch static rank factors,
   * the acceptance parity run: >= 3 staggered heterogeneous streams decode
-    token-identically to per-stream lock-step generate while two distinct
-    rank buckets are live in one fused step.
+    token-identically to each stream served alone by a 1-slot engine
+    while two distinct rank buckets are live in one fused step.
 """
 import jax
 import jax.numpy as jnp
@@ -18,9 +18,9 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import get_config
 from repro.configs.base import RankConfig
 from repro.models.api import get_model
-from repro.serve import PagedKVCache, Request, Scheduler, ServeEngine
+from repro.serve import (EngineConfig, PagedKVCache, Request, Scheduler,
+                         ServeEngine)
 from repro.serve.kv_cache import gather_views
-from repro.serve.scheduler import bucket_for, prefill_buckets
 
 
 pytestmark = pytest.mark.serve
@@ -38,12 +38,6 @@ def _cfg(mode="off", seg=8):
 # scheduler invariants
 # ---------------------------------------------------------------------------
 
-def test_prefill_buckets_cover_and_validate():
-    bks = prefill_buckets(100)
-    assert bks[-1] >= 100 and all(a < b for a, b in zip(bks, bks[1:]))
-    assert bucket_for(9, bks) == 16 and bucket_for(8, bks) == 8
-
-
 @settings(max_examples=15, deadline=None)
 @given(st.integers(0, 2 ** 16 - 1), st.integers(1, 4), st.integers(1, 12))
 def test_scheduler_invariants(seed, n_slots, n_reqs):
@@ -52,7 +46,7 @@ def test_scheduler_invariants(seed, n_slots, n_reqs):
     rnd = np.random.default_rng(seed)
     cfg = _cfg()
     cache = PagedKVCache(cfg, n_slots, max_len=32, page_size=8)
-    sched = Scheduler(n_slots, prefill_buckets(16))
+    sched = Scheduler(n_slots)
     reqs = [Request(rid=i, tokens=rnd.integers(0, 99, rnd.integers(1, 13)),
                     max_new=int(rnd.integers(1, 8)),
                     arrival=int(rnd.integers(0, 6)))
@@ -62,12 +56,11 @@ def test_scheduler_invariants(seed, n_slots, n_reqs):
     admitted_order = []
     for now in range(200):
         placed = sched.admit(now, cache.allocate)
-        for slot, req, bucket in placed:
-            assert bucket >= len(req.tokens)
+        for slot, req in placed:
             assert req.arrival <= now
             admitted_order.append(req.rid)
             cache.lens[slot] = len(req.tokens)
-            sched.slots[slot].prefilled = len(req.tokens)  # one-shot prefill
+            sched.slots[slot].prefilled = len(req.tokens)  # prompt consumed
             sched.slots[slot].n_out = 1
         # invariant: one live request per slot, disjoint live pages
         live = [s.req.rid for s in sched.slots if s.active]
@@ -98,7 +91,7 @@ def test_scheduler_invariants(seed, n_slots, n_reqs):
 def test_scheduler_rejects_oversized_and_blocks_fifo():
     cfg = _cfg()
     cache = PagedKVCache(cfg, 2, max_len=16, page_size=8)
-    sched = Scheduler(2, prefill_buckets(16))
+    sched = Scheduler(2)
     big = Request(rid=0, tokens=np.arange(10), max_new=10)   # needs 20 > 16
     sched.submit(big)
     sched.submit(Request(rid=1, tokens=np.arange(4), max_new=2))
@@ -360,7 +353,7 @@ def test_fullrank_basis_projection_matches_off():
 # ---------------------------------------------------------------------------
 
 def test_engine_parity_staggered_streams():
-    from repro.launch.serve import AdaptiveServer
+    from repro.serve import Engine, SamplingParams
     cfg = _cfg("adaptive", seg=8)
     fns = get_model(cfg)
     params = fns.init(RNG)
@@ -373,9 +366,10 @@ def test_engine_parity_staggered_streams():
     ]
     N = 16
     # 4 requests through 3 slots: the 4th stream rides a recycled slot,
-    # so stale-page masking is on the line too
-    eng = ServeEngine(cfg, params, n_slots=3, max_len=64, page_size=8,
-                      segment_len=8, max_new_cap=N)
+    # so stale-page masking is on the line too. The default chunk (16)
+    # splits the 20-token prompt.
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=3, max_len=64, page_size=8, segment_len=8, max_new_cap=N))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, tokens=p, max_new=N, arrival=2 * i))
     eng.warmup()
@@ -389,13 +383,18 @@ def test_engine_parity_staggered_streams():
                    for step in per_step)
     assert distinct >= 2, per_step
 
-    # token-for-token parity with per-stream lock-step generate
-    server = AdaptiveServer(cfg, params, max_len=64, page_size=8)
+    # token-for-token parity with each stream served alone, its whole
+    # prompt in one covering chunk
+    solo = Engine(cfg, params, config=EngineConfig(
+        n_slots=1, max_len=64, page_size=8, segment_len=8, max_new_cap=N,
+        prefill_chunk=max(len(p) for p in prompts)))
     for i, p in enumerate(prompts):
-        ref = server.generate(jnp.asarray(p[None]), N, segment_len=8)
+        solo.reset()
+        h = solo.submit(p, SamplingParams(max_new=N))
+        solo.run()
         np.testing.assert_array_equal(
-            outs[i], np.asarray(ref["tokens"])[0],
-            err_msg=f"stream {i} diverged from lock-step decode")
+            outs[i], h.result(),
+            err_msg=f"stream {i} diverged from the 1-slot engine")
 
 
 def test_engine_drift_trigger_forces_redecisions():
@@ -408,9 +407,9 @@ def test_engine_drift_trigger_forces_redecisions():
     prompt = np.arange(10, dtype=np.int32)
 
     def go(drift):
-        eng = ServeEngine(cfg, params, n_slots=1, max_len=48, page_size=8,
-                          segment_len=8, max_new_cap=12,
-                          drift_threshold=drift)
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=1, max_len=48, page_size=8, segment_len=8, max_new_cap=12,
+            drift_threshold=drift))
         eng.submit(Request(rid=0, tokens=prompt, max_new=12))
         outs = eng.run()
         return outs[0], eng.stats["decides"]
@@ -427,8 +426,8 @@ def test_engine_eos_eviction():
     fns = get_model(cfg)
     params = fns.init(RNG)
     prompt = np.arange(8, dtype=np.int32)
-    eng = ServeEngine(cfg, params, n_slots=1, max_len=48, page_size=8,
-                      max_new_cap=12)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=1, max_len=48, page_size=8, max_new_cap=12))
     eng.submit(Request(rid=0, tokens=prompt, max_new=12))
     outs = eng.run()
     full = outs[0]
@@ -437,8 +436,8 @@ def test_engine_eos_eviction():
     # first occurrence (which may be earlier)
     eos = int(full[3])
     stop = int(np.argmax(full == eos)) + 1
-    eng2 = ServeEngine(cfg, params, n_slots=1, max_len=48, page_size=8,
-                       max_new_cap=12)
+    eng2 = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=1, max_len=48, page_size=8, max_new_cap=12))
     eng2.submit(Request(rid=0, tokens=prompt, max_new=12, eos_id=eos))
     outs2 = eng2.run()
     assert outs2[0].tolist() == full[:stop].tolist()

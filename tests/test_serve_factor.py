@@ -22,9 +22,8 @@ from repro.configs.base import RankConfig
 from repro.models import transformer as tr
 from repro.models.api import get_model
 from repro.models.lowrank_cache import attention_mass
-from repro.serve import PagedKVCache, Request, ServeEngine
+from repro.serve import EngineConfig, PagedKVCache, Request, ServeEngine
 from repro.serve.policy import make_decide_fn
-from repro.serve.scheduler import bucket_for, prefill_buckets
 
 
 pytestmark = pytest.mark.serve
@@ -118,9 +117,9 @@ def test_serve_halfrank_agreement_weighted_basis():
 
 def _run_engine(cfg, params, prompts, *, factor, n_slots=2, max_new=12,
                 use_kernel=False):
-    eng = ServeEngine(cfg, params, n_slots=n_slots, max_len=64, page_size=8,
-                      segment_len=8, max_new_cap=max_new,
-                      factor_cache=factor, use_kernel=use_kernel)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=n_slots, max_len=64, page_size=8, segment_len=8,
+        max_new_cap=max_new, factor_cache=factor, use_kernel=use_kernel))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, tokens=p, max_new=max_new, arrival=2 * i))
     eng.run()
@@ -174,13 +173,15 @@ def test_recycled_slot_isolation():
     rnd = np.random.default_rng(2)
     p1 = rnd.integers(0, cfg.vocab_size, 14).astype(np.int32)
     p2 = rnd.integers(0, cfg.vocab_size, 11).astype(np.int32)
-    eng = ServeEngine(cfg, params, n_slots=1, max_len=48, page_size=8,
-                      segment_len=8, max_new_cap=10, factor_cache=True)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=1, max_len=48, page_size=8, segment_len=8, max_new_cap=10,
+        factor_cache=True))
     eng.submit(Request(rid=0, tokens=p1, max_new=10))
     eng.submit(Request(rid=1, tokens=p2, max_new=10))   # rides recycled slot
     outs = eng.run()
-    solo = ServeEngine(cfg, params, n_slots=1, max_len=48, page_size=8,
-                       segment_len=8, max_new_cap=10, factor_cache=True)
+    solo = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=1, max_len=48, page_size=8, segment_len=8, max_new_cap=10,
+        factor_cache=True))
     solo.submit(Request(rid=1, tokens=p2, max_new=10))
     outs_solo = solo.run()
     np.testing.assert_array_equal(
@@ -203,9 +204,9 @@ def test_recycled_slot_isolation_drrl():
     p2 = rnd.integers(0, cfg.vocab_size, 10).astype(np.int32)
 
     def serve(reqs):
-        eng = ServeEngine(cfg, params, policy, n_slots=1, max_len=48,
-                          page_size=8, segment_len=8, max_new_cap=10,
-                          factor_cache=True)
+        eng = ServeEngine(cfg, params, policy, config=EngineConfig(
+            n_slots=1, max_len=48, page_size=8, segment_len=8, max_new_cap=10,
+            factor_cache=True))
         for r in reqs:
             eng.submit(r)
         return eng.run()
@@ -219,25 +220,6 @@ def test_recycled_slot_isolation_drrl():
 # ---------------------------------------------------------------------------
 # satellite fixes
 # ---------------------------------------------------------------------------
-
-def test_prefill_buckets_clamped_to_max_len():
-    bks = prefill_buckets(100)
-    assert bks[-1] == 100 and bucket_for(100, bks) == 100
-    assert prefill_buckets(64)[-1] == 64          # powers of two unchanged
-    assert prefill_buckets(5)[-1] == 5
-    # an engine at a non-power-of-two max_len never compiles a prefill
-    # bucket (and cache) wider than a slot can hold
-    cfg = _drrl_cfg("off")
-    fns = get_model(cfg)
-    params = fns.init(RNG)
-    eng = ServeEngine(cfg, params, n_slots=1, max_len=20, page_size=8,
-                      max_new_cap=4)
-    assert max(eng._buckets) <= 20
-    eng.submit(Request(rid=0, tokens=np.arange(16, dtype=np.int32),
-                       max_new=4))
-    outs = eng.run()
-    assert outs[0].shape == (4,)
-
 
 def test_random_mode_folds_slot_into_key():
     """Two slots with identical K content at the same segment clock must

@@ -182,9 +182,9 @@ def test_split_invalidates_cut_and_insert_heals():
 
 def _run(cfg, params, prompts, *, prefix, n_slots=2, max_new=8, gap=8,
          **ekw):
-    eng = ServeEngine(cfg, params, n_slots=n_slots, max_len=64, page_size=8,
-                      segment_len=8, max_new_cap=max_new, prefill_chunk=8,
-                      prefix_cache=prefix, **ekw)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=n_slots, max_len=64, page_size=8, segment_len=8,
+        max_new_cap=max_new, prefill_chunk=8, prefix_cache=prefix, **ekw))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, tokens=p, max_new=max_new,
                            arrival=gap * i))
@@ -284,10 +284,10 @@ def test_prefix_parity_under_eviction_pressure():
                for base in (fam_a, fam_a, fam_b, fam_b, fam_a)]
 
     def run(prefix):
-        eng = ServeEngine(cfg, params, n_slots=1, max_len=32, page_size=8,
-                          segment_len=8, max_new_cap=4, prefill_chunk=8,
-                          prefix_cache=prefix,
-                          prefix_pages=0 if prefix else None)
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=1, max_len=32, page_size=8, segment_len=8, max_new_cap=4,
+            prefill_chunk=8, prefix_cache=prefix,
+            prefix_pages=0 if prefix else None))
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, tokens=p, max_new=4, arrival=0))
         return eng.run(), eng
@@ -310,9 +310,9 @@ def test_prefix_sampled_stream_parity():
     prompts = _shared_prefix_prompts(cfg, n=2, seed=6)
 
     def run(prefix):
-        eng = ServeEngine(cfg, params, n_slots=2, max_len=64, page_size=8,
-                          segment_len=8, max_new_cap=8, prefill_chunk=8,
-                          prefix_cache=prefix, sampling=True)
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=2, max_len=64, page_size=8, segment_len=8, max_new_cap=8,
+            prefill_chunk=8, prefix_cache=prefix, sampling=True))
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, tokens=p, max_new=8, arrival=8 * i,
                                temperature=0.7, top_k=12, seed=11 + i))
@@ -326,10 +326,8 @@ def test_prefix_sampled_stream_parity():
 def test_engine_reset_clears_tree_and_validation():
     cfg = _cfg("adaptive")
     params = get_model(cfg).init(RNG)
-    with pytest.raises(ValueError, match="prefix_cache"):
-        ServeEngine(cfg, params, prefill_chunk=None, prefix_cache=True)
-    with pytest.raises(ValueError, match="prefix_cache"):
-        EngineConfig(prefill_chunk=None, prefix_cache=True)
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        EngineConfig(prefill_chunk=0, prefix_cache=True)
     eng = Engine(cfg, params, config=EngineConfig(
         n_slots=2, max_len=64, page_size=8, prefill_chunk=8,
         max_new_cap=8, prefix_cache=True))

@@ -52,10 +52,10 @@ def _prompts(cfg, sizes=(9, 17, 12), seed=0):
 
 def _run(cfg, params, prompts, *, speculative, max_new=12, reqs=None,
          **ekw):
-    eng = ServeEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                      segment_len=8, max_new_cap=32, prefill_chunk=8,
-                      speculative=speculative, draft_k=3,
-                      draft_rank_frac=0.5, **ekw)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=4, max_len=64, page_size=8, segment_len=8, max_new_cap=32,
+        prefill_chunk=8, speculative=speculative, draft_k=3,
+        draft_rank_frac=0.5, **ekw))
     for i, p in enumerate(prompts):
         kw = dict(reqs[i]) if reqs else {}
         eng.submit(Request(rid=i, tokens=p, max_new=max_new, **kw))
@@ -301,10 +301,10 @@ def test_prng_stream_invariant_to_draft_k():
 
     outs = []
     for spec, k in [(False, None), (True, 1), (True, 3), (True, 5)]:
-        eng = ServeEngine(cfg, params, n_slots=2, max_len=64, page_size=8,
-                          segment_len=8, max_new_cap=32, prefill_chunk=8,
-                          speculative=spec, sampling=True,
-                          **({"draft_k": k} if k else {}))
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=2, max_len=64, page_size=8, segment_len=8, max_new_cap=32,
+            prefill_chunk=8, speculative=spec, sampling=True,
+            **({"draft_k": k} if k else {})))
         eng.submit(Request(rid=0, tokens=prompts[0], max_new=12, **reqs[0]))
         outs.append(eng.run()[0])
     for o in outs[1:]:
@@ -328,13 +328,12 @@ def test_snapshot_throttle_sparser_reuse_parity():
     reqs = [dict(arrival=10 * i) for i in range(2)]
     off, _ = _run(cfg, params, prompts, speculative=False, reqs=reqs)
 
-    dense_eng = ServeEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                            segment_len=8, max_new_cap=32, prefill_chunk=8,
-                            prefix_cache=True)
-    sparse_eng = ServeEngine(cfg, params, n_slots=4, max_len=64,
-                             page_size=8, segment_len=8, max_new_cap=32,
-                             prefill_chunk=8, prefix_cache=True,
-                             snapshot_every=2)
+    dense_eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=4, max_len=64, page_size=8, segment_len=8, max_new_cap=32,
+        prefill_chunk=8, prefix_cache=True))
+    sparse_eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=4, max_len=64, page_size=8, segment_len=8, max_new_cap=32,
+        prefill_chunk=8, prefix_cache=True, snapshot_every=2))
     for eng in (dense_eng, sparse_eng):
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, tokens=p, max_new=12, **reqs[i]))
@@ -357,12 +356,8 @@ def test_snapshot_throttle_sparser_reuse_parity():
 # ---------------------------------------------------------------------------
 
 def test_spec_config_validation():
-    cfg = _cfg("off")
-    params = get_model(cfg).init(RNG)
-    with pytest.raises(ValueError, match="speculative"):
-        ServeEngine(cfg, params, prefill_chunk=None, speculative=True)
-    with pytest.raises(ValueError, match="speculative"):
-        EngineConfig(prefill_chunk=None, speculative=True)
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        EngineConfig(prefill_chunk=0, speculative=True)
     with pytest.raises(ValueError, match="draft_k"):
         EngineConfig(speculative=True, draft_k=0)
     with pytest.raises(ValueError, match="draft_rank_frac"):
@@ -387,7 +382,7 @@ def test_adaptive_draft_parity_and_stats():
                      adaptive_draft=True)
     for i in range(len(prompts)):
         np.testing.assert_array_equal(outs[i], plain[i])
-    assert 0 <= eng.stats["eff_draft_k"] <= eng.draft_k
+    assert 0 <= eng.stats["eff_draft_k"] <= eng.config.draft_k
 
 
 def test_adaptive_draft_collapse_routes_plain_decode():
@@ -416,11 +411,10 @@ def test_adaptive_draft_recovers_from_collapse():
     params = get_model(cfg).init(RNG)
     prompts = _prompts(cfg)
     plain, _ = _run(cfg, params, prompts, speculative=False, max_new=24)
-    eng = ServeEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                      segment_len=8, max_new_cap=32, prefill_chunk=8,
-                      speculative=True, draft_k=3, draft_rank_frac=0.5,
-                      adaptive_draft=True, draft_shrink_below=-1.0,
-                      draft_grow_above=-1.0)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=4, max_len=64, page_size=8, segment_len=8, max_new_cap=32,
+        prefill_chunk=8, speculative=True, draft_k=3, draft_rank_frac=0.5,
+        adaptive_draft=True, draft_shrink_below=-1.0, draft_grow_above=-1.0))
     eng._eff_k = 0                      # start collapsed
     eng.stats["eff_draft_k"] = 0
     for i, p in enumerate(prompts):
@@ -428,14 +422,14 @@ def test_adaptive_draft_recovers_from_collapse():
     outs = eng.run()
     for i in range(len(prompts)):
         np.testing.assert_array_equal(outs[i], plain[i])
-    assert eng.stats["eff_draft_k"] == eng.draft_k
+    assert eng.stats["eff_draft_k"] == eng.config.draft_k
 
 
 def test_adaptive_draft_requires_speculative():
     cfg = _cfg("off")
     params = get_model(cfg).init(RNG)
     with pytest.raises(ValueError, match="adaptive_draft"):
-        ServeEngine(cfg, params, adaptive_draft=True)
+        ServeEngine(cfg, params, config=EngineConfig(adaptive_draft=True))
     with pytest.raises(ValueError, match="adaptive_draft"):
         EngineConfig(adaptive_draft=True)
 
@@ -453,10 +447,10 @@ def test_drift_check_once_per_accepted_run_post_accept():
     cfg = _cfg("adaptive")
     params = get_model(cfg).init(RNG)
     prompts = _prompts(cfg)
-    eng = ServeEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
-                      segment_len=8, max_new_cap=32, prefill_chunk=8,
-                      speculative=True, draft_k=3, draft_rank_frac=0.5,
-                      drift_threshold=1e9)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        n_slots=4, max_len=64, page_size=8, segment_len=8, max_new_cap=32,
+        prefill_chunk=8, speculative=True, draft_k=3, draft_rank_frac=0.5,
+        drift_threshold=1e9))
     calls = []
     orig = eng._check_drift
 

@@ -29,7 +29,7 @@ import jax
 from repro.configs import get_config
 from repro.configs.base import RankConfig
 from repro.models.api import get_model
-from repro.serve import Request, ServeEngine
+from repro.serve import EngineConfig, Request, ServeEngine
 from repro.serve.traces import TRACE_SCHEMA_VERSION, TraceReader, TraceRecorder
 from repro.serve.workloads import build, make_workload, workload_names
 
@@ -59,10 +59,10 @@ def _record_suite(cfg, params, directory, *, seed=3, n_requests=4,
         spec = make_workload(name, seed=seed, n_requests=n_requests,
                              max_new=max_new, vocab=cfg.vocab_size,
                              max_prompt=40)
-        eng = ServeEngine(cfg, params, n_slots=4, max_len=96, page_size=16,
-                          segment_len=8, max_new_cap=max_new,
-                          prefill_chunk=8, record_traces=rec,
-                          **spec.engine_overrides)
+        eng = ServeEngine(cfg, params, config=EngineConfig(
+            n_slots=4, max_len=96, page_size=16, segment_len=8,
+            max_new_cap=max_new, prefill_chunk=8, record_traces=rec,
+            **spec.engine_overrides))
         for r in build(spec):
             eng.submit(r)
         outs = eng.run()
@@ -205,9 +205,9 @@ def test_train_and_serve_learned(model, tmp_path):
                     jax.tree_util.tree_leaves(pol2)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     lcfg = _cfg("learned")
-    eng = ServeEngine(lcfg, params, pol2, n_slots=2, max_len=64,
-                      page_size=16, segment_len=8, max_new_cap=8,
-                      prefill_chunk=8)
+    eng = ServeEngine(lcfg, params, pol2, config=EngineConfig(
+        n_slots=2, max_len=64, page_size=16, segment_len=8, max_new_cap=8,
+        prefill_chunk=8))
     rnd = np.random.default_rng(0)
     for i in range(2):
         eng.submit(Request(rid=i, tokens=rnd.integers(
@@ -242,5 +242,5 @@ def test_load_policy_missing_meta(tmp_path):
 def test_policy_mode_requires_params(model, mode):
     _, params = model
     with pytest.raises(ValueError, match="needs policy params"):
-        ServeEngine(_cfg(mode), params, n_slots=2, max_len=64,
-                    page_size=16, segment_len=8)
+        ServeEngine(_cfg(mode), params, config=EngineConfig(
+            n_slots=2, max_len=64, page_size=16, segment_len=8))

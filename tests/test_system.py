@@ -80,21 +80,38 @@ def test_drrl_flops_reduction_vs_fidelity():
 
 
 def test_adaptive_server_rank_dispatch():
-    from repro.launch.serve import AdaptiveServer
+    """Two streams through one engine: each step records a rank per
+    stream from the grid, and each stream decodes as a 1-slot engine
+    serving it alone would."""
+    from repro.serve import Engine, EngineConfig, SamplingParams
     cfg = get_config("drrl-paper", reduced=True).with_(
         rank=RankConfig(mode="adaptive", rank_grid=(4, 8, 12, 16),
                         segment_len=8))
     fns = get_model(cfg)
     params = fns.init(RNG)
-    server = AdaptiveServer(cfg, params, max_len=96)
-    prompts = jax.random.randint(RNG, (2, 16), 0, cfg.vocab_size)
-    res = server.generate(prompts, 24, segment_len=8)
-    assert res["tokens"].shape == (2, 24)
-    # per-step per-stream rank record: 23 fused steps, both streams live
-    assert len(res["ranks"]) == 23
-    used = {r for step in res["ranks"] for r in step}
+    prompts = np.asarray(jax.random.randint(RNG, (2, 16), 0,
+                                            cfg.vocab_size))
+    eng = Engine(cfg, params, config=EngineConfig(
+        n_slots=2, max_len=96, segment_len=8, max_new_cap=24))
+    hs = [eng.submit(p, SamplingParams(max_new=24)) for p in prompts]
+    eng.warmup()
+    eng.run()
+    tokens = np.stack([h.result() for h in hs])
+    assert tokens.shape == (2, 24)
+    # per-step per-stream rank record: one prefill step (the 16-token
+    # prompts fit the default chunk), then 23 decode steps
+    ranks = [r.tolist() for r in eng.core.ranks_per_step()]
+    assert len(ranks) == 24
+    used = {r for step in ranks for r in step}
     assert used <= set(cfg.rank.rank_grid) | {-1}
-    assert res["compile_s"] > 0.0 and res["tok_per_s"] > 0.0
+    assert eng.stats["compile_s"] > 0.0 and eng.stats["decode_s"] > 0.0
+    solo = Engine(cfg, params, config=EngineConfig(
+        n_slots=1, max_len=96, segment_len=8, max_new_cap=24))
+    for i, p in enumerate(prompts):
+        solo.reset()
+        h = solo.submit(p, SamplingParams(max_new=24))
+        solo.run()
+        np.testing.assert_array_equal(tokens[i], h.result())
 
 
 def test_grad_accumulation_matches_single_batch():

@@ -29,7 +29,7 @@ from repro.core.drrl import init_agent
 from repro.kernels.decode_attn import flash_decode
 from repro.models.api import get_model
 from repro.models.attention import attend
-from repro.serve import PagedKVCache, ServeEngine
+from repro.serve import EngineConfig, PagedKVCache, ServeEngine
 
 HQ = HKV = 12
 DH = 64
@@ -154,8 +154,8 @@ def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
         num_layers=4, max_seq_len=32768,
         rank=RankConfig(mode="drrl", rank_grid=grid, segment_len=512))
     agent = init_agent(jax.random.PRNGKey(1), cfg.rank, cfg.d_model)
-    eng = ServeEngine(cfg, None, agent, n_slots=1, max_len=16,
-                      max_new_cap=max_new, prefill_chunk=16)
+    eng = ServeEngine(cfg, None, agent, config=EngineConfig(
+        n_slots=1, max_len=16, max_new_cap=max_new, prefill_chunk=16))
 
     def spec(x, dtype=None):
         if isinstance(x, tuple):
