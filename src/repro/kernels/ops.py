@@ -10,6 +10,7 @@ import jax
 
 from repro.kernels.decode_attn import flash_decode
 from repro.kernels.lowrank_flash import lowrank_flash
+from repro.kernels.paged_attn import paged_decode_attention
 
 
 def _on_cpu() -> bool:
@@ -45,3 +46,19 @@ def decode_attention(q, k, v, kv_len, *, scale: float, block_k: int = 512,
     return flash_decode(q, k, v, kv_len, scale=scale, block_k=block_k,
                         interpret=interpret, return_probs=return_probs,
                         q_start=q_start, q_lens=q_lens)
+
+
+def paged_attention(q, k_pool, v_pool, layer, page_table, kv_end, q_start,
+                    q_lens, *, scale: float, return_mass: bool = False,
+                    interpret=None):
+    """The fused serve step's attention read straight from the page pools:
+    q (ns, C, hq, dh) against each row's pages of ``k_pool``/``v_pool``
+    (L, P, page_size, hkv * dh) at ``layer``, up to the row's ``kv_end``.
+    ``return_mass`` also returns the per-key attention mass (ns, hkv, M).
+    See repro.kernels.paged_attn."""
+    if interpret is None:
+        interpret = _on_cpu()
+    return paged_decode_attention(q, k_pool, v_pool, layer, page_table,
+                                  kv_end, q_start, q_lens, scale=scale,
+                                  return_mass=return_mass,
+                                  interpret=interpret)

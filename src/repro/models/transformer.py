@@ -287,6 +287,27 @@ def decode_step_dense(cfg: ModelConfig, params, cache, tokens, *,
     return logits, {"k": nk, "v": nv, "len": cache["len"] + s}
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def paged_attention_selected(cfg: ModelConfig, *, chunk: int, max_len: int,
+                             page_size: int, pool_dtype, factored: bool,
+                             mass_defer: bool = False) -> bool:
+    """Whether the fused step attends with the Pallas kernel that reads
+    the K/V pages in place (:mod:`repro.kernels.paged_attn`): on a TPU,
+    where K is stored dense (no factor pool) and the step accumulates its
+    attention mass in-graph (the speculative verify defers it per query
+    instead), for shapes the kernel serves. Everywhere else, the CPU among
+    them, the step gathers the pages and attends in XLA."""
+    from repro.kernels.paged_attn import fits
+    return (_on_tpu() and not factored and not mass_defer
+            and fits(chunk=chunk, hq=cfg.num_heads, hkv=cfg.num_kv_heads,
+                     dh=cfg.resolved_head_dim(), max_len=max_len,
+                     page_size=page_size,
+                     pool_itemsize=jnp.dtype(pool_dtype).itemsize))
+
+
 def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                       tokens, *, slot_lens, slot_ranks=None, basis=None,
                       active=None, use_kernel: bool = False,
@@ -375,6 +396,15 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     caller donates the pools touches only the positions it writes and the
     pages it reads, whatever the pool's size.
 
+    **Paged read** (:func:`paged_attention_selected`): on a TPU with dense
+    K and the mass accumulated in-graph, no page is gathered at all. The
+    Pallas kernel :func:`repro.kernels.paged_attn.paged_decode_attention`
+    reads each row's live pages from the carried pools through the page
+    table and stops at the row's ``kv_end``. K is read as stored, so the
+    rank projection moves to the query side, an exact re-association for
+    any basis: ``(q B ⊙ col_ok)(K B)ᵀ = ((q B ⊙ col_ok) Bᵀ) Kᵀ``. Decode
+    rows take the re-projected q, mid-prefill rows keep q.
+
     Returns (logits (n_slots, 1, V), pools) with pools a dict holding the
     updated ``k``/``v`` pools plus ``kt``/``mass`` when those were given.
     """
@@ -438,6 +468,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
     score_dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
         cfg.softmax_dtype]
     scale = dh ** -0.5
+    paged = paged_attention_selected(
+        cfg, chunk=C, max_len=M, page_size=ps, pool_dtype=pool_k.dtype,
+        factored=kt_pool is not None, mass_defer=mass_defer)
     if slot_ranks is not None:
         r_keep = basis.shape[-1]
         col_ok = (jnp.arange(r_keep)[None, :]
@@ -477,8 +510,9 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                     kt_new.astype(ktp.dtype))
         rank_on = rcfg.mode != "off" and slot_ranks is not None
         with jax.named_scope("kv_read"):
-            vg = vp[li, page_table].reshape(ns, M, hkv, dh)
-            if not rank_on or ktp is None or mixed:
+            if not paged:
+                vg = vp[li, page_table].reshape(ns, M, hkv, dh)
+            if not paged and (not rank_on or ktp is None or mixed):
                 kg = kp[li, page_table].reshape(ns, M, hkv, dh)
                 # stale page contents (freed + re-issued pages) must not
                 # leak: zero everything beyond the valid prefix
@@ -489,7 +523,20 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                 k_fac = (ktg * valid[:, :, None, None].astype(ktg.dtype)
                          ).astype(x.dtype)
         if not rank_on:
-            q_use, k_use = q, k_dense
+            q_use, k_use = q, (None if paged else k_dense)
+        elif paged:
+            # the score against K as stored: q projected onto the basis,
+            # masked to the slot's rank and projected back
+            with jax.named_scope("rank_proj"):
+                q_fac = jnp.einsum(
+                    "bmgd,bgdr->bmgr",
+                    group_q_heads(q.astype(jnp.float32), hkv),
+                    basis_l) * col_ok[:, None, None, :]
+                q_back = ungroup_q_heads(jnp.einsum(
+                    "bmgr,bgdr->bmgd", q_fac, basis_l), C)
+                q_use = (jnp.where(is_pf[:, None, None, None],
+                                   q.astype(jnp.float32), q_back)
+                         if mixed else q_back)
         else:
             # project q onto the slot's cached segment eigenbasis; per-row
             # rank = zeroed q columns beyond the slot's bucket (the score
@@ -520,7 +567,16 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
         with jax.named_scope("attend"):
             probs = None
             want_probs = (mp is not None) or mass_defer
-            if use_kernel:
+            if paged:
+                from repro.kernels.ops import paged_attention
+                res = paged_attention(
+                    q_use, kp, vp, li, page_table, kv_end, slot_lens,
+                    # a dead lane has no valid query: no page is read
+                    jnp.where(active, q_lens, 0), scale=scale,
+                    return_mass=mp is not None)
+                o, w_tok = res if mp is not None else (res, None)
+                o = o.astype(x.dtype)                        # (ns, C, hq, dh)
+            elif use_kernel:
                 from repro.kernels.ops import decode_attention
                 qk = jnp.swapaxes(q_use, 1, 2)               # (ns, hq, C, r)
                 res = decode_attention(
@@ -559,9 +615,10 @@ def decode_step_paged(cfg: ModelConfig, params, pool_k, pool_v, page_table,
                 # recycled slot's stale mass dies the moment the position is
                 # reused — no eager pool-wide zeroing at admission.
                 from repro.models.common import kv_group_mean
-                w = (probs.astype(jnp.float32)
-                     * write_ok[:, None, :, None]).sum(axis=2)   # (ns, hq, M)
-                w_tok = kv_group_mean(w, hkv)                    # (ns, hkv, M)
+                if not paged:
+                    w = (probs.astype(jnp.float32)
+                         * write_ok[:, None, :, None]).sum(axis=2)  # (ns, hq, M)
+                    w_tok = kv_group_mean(w, hkv)                   # (ns, hkv, M)
                 mp_l = (jnp.where(new_cell[:, :, None], 0.0, mp[li])
                         + jnp.swapaxes(w_tok, 1, 2).astype(mp.dtype))
                 mp = jax.lax.dynamic_update_index_in_dim(mp, mp_l, li, 0)

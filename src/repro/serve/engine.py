@@ -35,6 +35,7 @@ request carries an ``eos_id`` or a streaming consumer is attached).
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -44,6 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attn import block_pages
+from repro.models import transformer
 from repro.models.api import get_model
 from repro.obs import Observability, Stopwatch
 from repro.serve.config import EngineConfig
@@ -112,6 +115,16 @@ class ServeEngine:
             n_pages = c.n_slots * pps + 1 + extra
         self.cache = PagedKVCache(cfg, c.n_slots, c.max_len, c.page_size,
                                   n_pages=n_pages, factored=c.factor_cache)
+        # whether the plain fused steps (pure decode, mixed) attend with
+        # the paged Pallas kernel: the choice decode_step_paged makes when
+        # it is traced, repeated here for the serve.attn.* counters
+        chosen = functools.partial(
+            transformer.paged_attention_selected, cfg,
+            max_len=self.cache.max_len, page_size=c.page_size,
+            pool_dtype=self.cache.k_pool.dtype, factored=self.cache.factored)
+        self._paged_attn = {False: chosen(chunk=1),
+                            True: chosen(chunk=c.prefill_chunk)}
+        self._attn_block = (block_pages(pps, c.page_size), c.page_size)
         # static draft width: the basis / kt pool are sliced to r_cap
         # columns for the draft forwards (a real byte cut); per-row draft
         # ranks (policy.draft_ranks) stay within [grid floor, r_cap]
@@ -205,7 +218,8 @@ class ServeEngine:
                  "prefix_cow": 0, "prefix_evictions": 0,
                  "spec_steps": 0, "spec_drafted": 0,
                  "spec_accepted": 0, "spec_tokens": 0,
-                 "eff_draft_k": k0},
+                 "eff_draft_k": k0, "attn.paged_steps": 0,
+                 "attn.pages_read": 0, "attn.pages_spanned": 0},
                 refresh=self._drain_rank_counters)
             # per-decision (ranks, slot, vetoed) as UNFETCHED device values:
             # _drain_rank_counters fetches them in one batch when the rank
@@ -1162,6 +1176,8 @@ class ServeEngine:
                 # through the mixed step too — dispatching _step here
                 # would compile in steady state
                 use_mixed = bool(mid) or self.config.speculative
+                if self._paged_attn[use_mixed]:
+                    self._count_paged_read(live, q_host)
                 step_fn = self._step_mixed if use_mixed else self._step
                 extra = (self.prompt_buf,) if use_mixed else ()
                 pools, tok, ob, lens = step_fn(
@@ -1227,6 +1243,20 @@ class ServeEngine:
         self.obs.on_step(queued=len(self.sched.pending), live=len(live),
                          kv_positions=sum(st.prefilled + st.n_out
                                           for st in live))
+
+    def _count_paged_read(self, live: List[int], q_host: Dict[int, int]
+                          ) -> None:
+        """``serve.attn.*`` for a plain fused step that runs the paged
+        kernel (the speculative step is not counted): the pages its
+        blocks cover, each live row up to its kv_end in whole blocks
+        (``pages_read``), against every slot's whole page table
+        (``pages_spanned``), both reckoned from the host's lengths."""
+        bp, ps = self._attn_block
+        ends = self.cache.lens[live] + [q_host.get(i, 1) for i in live]
+        self.stats["attn.paged_steps"] += 1
+        self.stats["attn.pages_read"] += int((-(-ends // (bp * ps))).sum()
+                                             * bp)
+        self.stats["attn.pages_spanned"] += self.cache.page_table.size
 
     def run(self, max_steps: Optional[int] = None) -> Dict:
         """Drive the loop until every request finished. Returns

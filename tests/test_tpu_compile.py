@@ -13,6 +13,12 @@ published widths, bfloat16, 8 slots of 12288 positions, the factor pool
 on) with room to spare, and its fused steps must read each of the 8 kv
 heads once for its 6 q heads instead of repeating K and V to 48 heads.
 
+At the drrl-paper chat deployment (32 slots of 2048 positions, dense K)
+both fused steps attend with the paged Pallas kernel, which reads the
+carried pools in place: no gathered or re-tiled K/V and no whole-pool
+copy. The longctx steps, which read K in factor form, keep their XLA
+attention.
+
 Nothing runs: these catch what interpret mode cannot (tiling, fast-memory
 budget) at no chip time. The topology is described inside a fixture, so
 collection never loads the TPU library; without it the tests skip."""
@@ -26,7 +32,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.configs.base import RankConfig
 from repro.core.drrl import init_agent
+from repro.kernels import ops
 from repro.kernels.decode_attn import flash_decode
+from repro.models import transformer
 from repro.models.api import get_model
 from repro.models.attention import attend
 from repro.serve import EngineConfig, PagedKVCache, ServeEngine
@@ -119,15 +127,21 @@ def _mixed_step_compiled(sharding, n_slots=32, max_len=2048, chunk=16):
     return compiled, cache["k_pool"].shape[1]
 
 
-def test_mixed_step_keeps_pools_in_place_for_v5e(one_chip):
-    compiled, n_pages = _mixed_step_compiled(one_chip)
+def _pool_moves(text, n_pages):
+    """Copies and (dynamic) slices of a whole page pool."""
     moved = []
     op = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) "
                     r"(copy|dynamic-slice|dynamic-update-slice)\(")
-    for line in compiled.as_text().splitlines():
+    for line in text.splitlines():
         m = op.match(line)
         if m and re.search(rf"[\[,]{n_pages}[,\]]", m.group(2)):
             moved.append(m.group(1, 3, 2))
+    return moved
+
+
+def test_mixed_step_keeps_pools_in_place_for_v5e(one_chip):
+    compiled, n_pages = _mixed_step_compiled(one_chip)
+    moved = _pool_moves(compiled.as_text(), n_pages)
     assert not moved, f"whole-pool copies or slices: {moved[:8]}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.5e9, f"{temp / 1e9:.2f} GB of temporaries"
@@ -144,20 +158,22 @@ STEP_LIMIT = 12e9
 DECIDE_TEMP_LIMIT = 1e9
 
 
-def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
-    """The engine's two fused steps and ``decide`` at the longctx
-    deployment, lowered on shapes only, donating what the engine donates
-    on a chip. The engine is built at a tiny size: the executables take
-    every pool and weight as arguments, so only the widths come from it."""
-    grid = (16, 24, 32, 40, 48, 56, 64)
-    cfg = get_config("internlm2-20b").with_(
-        num_layers=4, max_seq_len=32768,
-        rank=RankConfig(mode="drrl", rank_grid=grid, segment_len=512))
+def _engine_programs(sharding, cfg, *, n_slots, max_len, max_new,
+                     decide=False):
+    """The engine's two fused steps (and ``decide``) at a deployment,
+    lowered on shapes only, donating what the engine donates on a chip.
+    The engine is built at a tiny size: the executables take every pool
+    and weight as arguments, so only the widths come from it. The fused
+    steps choose their attention as on a chip: the platform check of
+    ``paged_attention_selected`` answers TPU, and the Pallas kernels
+    compile through Mosaic instead of the interpreter."""
     agent = init_agent(jax.random.PRNGKey(1), cfg.rank, cfg.d_model)
     eng = ServeEngine(cfg, None, agent, config=EngineConfig(
         n_slots=1, max_len=16, max_new_cap=max_new, prefill_chunk=16))
 
     def spec(x, dtype=None):
+        if x is None:
+            return None
         if isinstance(x, tuple):
             x = jax.ShapeDtypeStruct(x, dtype)
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
@@ -169,7 +185,6 @@ def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
         for pc in [PagedKVCache(cfg, n_slots, max_len)]
         for k in ("k_pool", "v_pool", "kt_pool", "mass_pool", "basis",
                   "spectra", "ranks")})
-    assert c["kt_pool"] is not None          # r_keep 64 < head_dim 128
     c = {k: spec(v) for k, v in c.items()}
     rows = spec((n_slots,), jnp.int32)
     pt = spec((n_slots, max_len // 16), jnp.int32)
@@ -180,16 +195,33 @@ def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
             spec((n_slots,), jnp.float32), rows,
             spec((n_slots,), jnp.float32), spec((n_slots,), jnp.uint32)]
     donate = (1, 2, 3, 4, 11)
-    out = {"step": jax.jit(eng._step_impl, donate_argnums=donate)
-           .lower(*args).compile(),
-           "mixed": jax.jit(eng._step_mixed_impl, donate_argnums=donate)
-           .lower(*args, spec((n_slots, max_len), jnp.int32)).compile()}
-    scalar = spec((), jnp.int32)
-    out["decide"] = jax.jit(eng._decide.__wrapped__,
-                            donate_argnums=(2, 6, 7)).lower(
-        c["k_pool"], c["mass_pool"], c["kt_pool"], pt, rows, c["ranks"],
-        c["basis"], c["spectra"], scalar, spec((), bool), scalar).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(transformer, "_on_tpu", lambda: True)
+        mp.setattr(ops, "_on_cpu", lambda: False)
+        out = {"step": jax.jit(eng._step_impl, donate_argnums=donate)
+               .lower(*args).compile(),
+               "mixed": jax.jit(eng._step_mixed_impl, donate_argnums=donate)
+               .lower(*args, spec((n_slots, max_len), jnp.int32)).compile()}
+    out["kv_pages"] = c["k_pool"].shape[1]
+    if decide:
+        scalar = spec((), jnp.int32)
+        out["decide"] = jax.jit(eng._decide.__wrapped__,
+                                donate_argnums=(2, 6, 7)).lower(
+            c["k_pool"], c["mass_pool"], c["kt_pool"], pt, rows, c["ranks"],
+            c["basis"], c["spectra"], scalar, spec((), bool),
+            scalar).compile()
     return out
+
+
+def _longctx_programs(sharding, n_slots=8, max_len=12288, max_new=4096):
+    """The fused steps and ``decide`` at the longctx deployment."""
+    grid = (16, 24, 32, 40, 48, 56, 64)
+    cfg = get_config("internlm2-20b").with_(
+        num_layers=4, max_seq_len=32768,
+        rank=RankConfig(mode="drrl", rank_grid=grid, segment_len=512))
+    assert PagedKVCache(cfg, 1, 16).factored    # r_keep 64 < head_dim 128
+    return _engine_programs(sharding, cfg, n_slots=n_slots, max_len=max_len,
+                            max_new=max_new, decide=True)
 
 
 @pytest.fixture(scope="module")
@@ -286,3 +318,43 @@ def test_grouped_attend_compiles_as_mha_at_one_kv_head_per_q_head(one_chip,
     got, want = (_hlo_ops(jax.jit(f).lower(*args).compile())
                  for f in (grouped, mha))
     assert got == want
+
+
+# The chat deployment: drrl-paper (12 q and 12 kv heads of 64, float32)
+# serving 32 slots of 2048 positions with 16-token prefill chunks; K is
+# stored dense (r_keep 64 == head_dim). Before the paged kernel each
+# layer of each step gathered every slot's pages, [4096,16,768], and
+# re-tiled them to [32,2048,12,64] for K and V.
+CHAT_SLOTS, CHAT_LEN, CHAT_SHAPES = 32, 2048, ("[32,2048,12,64]",
+                                               "[4096,16,768]")
+
+
+@pytest.fixture(scope="module")
+def chat_programs(one_chip):
+    grid = (16, 24, 32, 40, 48, 56, 64)
+    cfg = get_config("drrl-paper").with_(
+        rank=RankConfig(mode="drrl", rank_grid=grid, segment_len=512))
+    return _engine_programs(one_chip, cfg, n_slots=CHAT_SLOTS,
+                            max_len=CHAT_LEN, max_new=512)
+
+
+@pytest.mark.parametrize("name", ["step", "mixed"])
+def test_chat_steps_read_pages_in_place_for_v5e(chat_programs, name):
+    """Both fused steps of the chat deployment attend with the paged
+    kernel, which reads the carried pools in place: no gathered or
+    re-tiled K/V, and no copy of a whole pool."""
+    text = chat_programs[name].as_text()
+    assert "paged_decode_attention" in text
+    assert "tpu_custom_call" in text
+    found = [s for s in CHAT_SHAPES if s in text]
+    assert not found, f"{name}: gathered or re-tiled K/V {found}"
+    moved = _pool_moves(text, chat_programs["kv_pages"])
+    assert not moved, f"{name}: whole-pool copies or slices {moved[:8]}"
+
+
+def test_longctx_steps_keep_the_factor_read(longctx_programs):
+    """The factor-form K read (longctx: a factor pool beside dense K)
+    keeps its XLA attention: the paged kernel is chosen only where K is
+    stored dense."""
+    for name in ("step", "mixed"):
+        assert "paged_decode_attention" not in longctx_programs[name].as_text()
